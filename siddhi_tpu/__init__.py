@@ -13,25 +13,19 @@ import jax
 # types; must be set before any array is created.
 jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: TPU first-compiles of window/NFA steps
-# run 20-60 s; caching makes every later process start in ~2 s (measured).
-# Opt out with SIDDHI_TPU_NO_CACHE=1 or point elsewhere with
-# SIDDHI_TPU_CACHE_DIR (default: ./.jax_cache, shared with bench.py).
-# Every compile persists (min compile time / entry size 0): warm starts
-# must hit for the small CPU-compiled steps too, not just the minute-long
-# TPU ones — see docs/compile_cache.md for the cache-key stability rules
-# that keep the entries reusable across processes.
-if not os.environ.get("SIDDHI_TPU_NO_CACHE"):
-    _cache = os.environ.get(
-        "SIDDHI_TPU_CACHE_DIR",
-        os.path.join(os.path.abspath(os.curdir), ".jax_cache"))
-    try:
-        os.makedirs(_cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", _cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — cache is best-effort
-        pass
+# Persistent XLA compilation cache (docs/compile_cache.md). Where
+# JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and nothing here
+# names a directory; otherwise the cache lives at a fixed
+# <checkout>/.jax_cache, whatever the working directory (the path is part
+# of the cache key, so a moving directory never hits). Every compile
+# persists (min compile time / entry size 0) so warm starts hit for small
+# programs too. JAX_ENABLE_COMPILATION_CACHE=false turns it off.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 from .core.manager import SiddhiManager  # noqa: E402
 from .core.persistence import (  # noqa: E402

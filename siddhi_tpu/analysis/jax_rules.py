@@ -3,9 +3,9 @@
 Static versions of the invariants this codebase already paid to learn
 (see docs/tpu_hygiene.md and tests/test_dispatch_hygiene.py):
 
-- a module-level jax array captured as a constant by a jitted step knocks
-  the process off the fast dispatch path (~2.4 ms added to EVERY
-  dispatch, measured on TPU v5-lite);
+- a module-level jax array initializes the device backend at import
+  (the importing process then holds the chip) and, captured by a jitted
+  step, is one more device buffer per program (ops/sentinels.py);
 - host syncs (``jax.device_get``, ``jax.block_until_ready``,
   ``.item()``, ``int()``/``float()`` on device values) inside Python
   loops serialize the device pipeline once per iteration instead of
@@ -98,8 +98,8 @@ def _src(node: ast.AST) -> str:
 
 @register(
     "module-device-array", ERROR,
-    "a module-level jax array captured by a jitted step adds ~2.4 ms to "
-    "every subsequent dispatch; module constants must be numpy")
+    "a module-level jax array initializes the backend at import and is "
+    "captured by jitted steps; module constants must be numpy")
 def module_device_array(ctx: ModuleContext) -> Iterator[Finding]:
     for node in ctx.nodes:
         if not isinstance(node, ast.Call):

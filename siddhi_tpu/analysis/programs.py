@@ -55,6 +55,7 @@ from typing import Iterable, Optional
 
 import jax
 import jax.numpy as jnp
+import jax.extend.core as jax_core
 import numpy as np
 
 from .findings import ERROR, WARNING, Finding
@@ -75,11 +76,13 @@ PROGRAM_RULES = (RULE_DONATION, RULE_HOST, RULE_DTYPE, RULE_BUDGET)
 
 # jaxpr primitives that cross the host boundary inside a compiled step
 _CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed",
 })
 
-_UNALIASED_RE = re.compile(r"ShapedArray\((\w+)\[([\d,]*)\]")
+# "Some donated buffers were not usable: float64[512,64], int32[8]."
+_UNALIASED_RE = re.compile(r"\b([a-z]\w*)\[([\d,]*)\]")
 
 
 def donate_min_bytes_from_env() -> int:
@@ -131,9 +134,9 @@ def _iter_eqns(jaxpr):
 
 
 def _iter_param_eqns(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax_core.ClosedJaxpr):
         yield from _iter_eqns(v.jaxpr)
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax_core.Jaxpr):
         yield from _iter_eqns(v)
     elif isinstance(v, (tuple, list)):
         for item in v:

@@ -5,10 +5,10 @@ Every query in an app compiles to one (or a few) jitted step programs.
 Left to the default lazy path, those programs compile serially, one at a
 time, on the first chunk that reaches each query — for a realistic app
 that is minutes of wall clock before the first result, paid AFTER
-traffic has started arriving (the r01..r05 bench rounds all died inside
-this phase). Siddhi deploys in milliseconds because its executor tree
-is interpreted; the TPU build gets the same deploy-time behavior by
-compiling everything up front, in parallel, and persisting the results:
+traffic has started arriving. Siddhi deploys in milliseconds because its
+executor tree is interpreted; the TPU build gets the same deploy-time
+behavior by compiling everything up front, in parallel, and persisting the
+results:
 
 1. `CompileService.specs(buckets)` enumerates every jitted step the app
    can dispatch for the configured ingest buckets — per-query row +
@@ -23,8 +23,10 @@ compiling everything up front, in parallel, and persisting the results:
    guarantees the dispatch-path caches are the ones that get hot: the
    first real chunk performs zero traces and zero compiles.
 3. Compiles are persisted via JAX's compilation cache
-   (`SIDDHI_TPU_CACHE_DIR`, wired in `siddhi_tpu/__init__.py` with
-   min-compile-time/min-entry-size 0 so every program is written).
+   (`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`, wired in
+   `siddhi_tpu/__init__.py` with min-compile-time/min-entry-size 0 so
+   every program is written). A spec that fails raises `WarmupError`
+   once every spec was tried — nothing is left to compile lazily.
    A warm process start loads executables from disk instead of
    recompiling; the hit/miss counters below make that observable.
 
@@ -43,7 +45,6 @@ and at DETAIL stats level the per-step timing list.
 from __future__ import annotations
 
 import contextlib
-import logging
 import os
 import threading
 import time
@@ -149,8 +150,17 @@ def spec_args_abstract() -> bool:
     return _abstract()
 
 
+def _mesh_sharding(x):
+    """The leaf's sharding when it spans several devices (mesh-placed
+    state), else None. Warm args must carry it: the program traffic
+    dispatches on a mesh is the sharded one, not a device-0 twin."""
+    sh = getattr(x, "sharding", None)
+    return sh if sh is not None and len(sh.device_set) > 1 else None
+
+
 def _sds(x) -> jax.ShapeDtypeStruct:
-    return jax.ShapeDtypeStruct(tuple(jnp.shape(x)), jnp.result_type(x))
+    return jax.ShapeDtypeStruct(tuple(jnp.shape(x)), jnp.result_type(x),
+                                sharding=_mesh_sharding(x))
 
 
 def zeros_array(shape, dtype):
@@ -166,7 +176,8 @@ def _zeros_like_tree(tree):
     if _abstract():
         return jax.tree_util.tree_map(_sds, tree)
     return jax.tree_util.tree_map(
-        lambda x: jnp.zeros(jnp.shape(x), jnp.result_type(x)), tree)
+        lambda x: jnp.zeros(jnp.shape(x), jnp.result_type(x),
+                            device=_mesh_sharding(x)), tree)
 
 
 def _zero_batch(schema: StreamSchema, capacity: int) -> EventBatch:
@@ -202,6 +213,16 @@ def _zero_now():
     return jnp.asarray(0, dtype=jnp.int64)
 
 
+class WarmupError(RuntimeError):
+    """One or more warmup specs failed to compile or run."""
+
+    def __init__(self, app_name: str, errors: list):
+        self.errors = errors
+        super().__init__(
+            f"app '{app_name}': {len(errors)} warmup spec(s) failed: "
+            f"{errors[:3]}")
+
+
 class CompileSpec:
     """One warmable program: a display key + a builder that returns
     (jitted_fn, args). The builder runs on the main thread (it touches
@@ -229,6 +250,7 @@ class CompileService:
         self.sharded_programs = 0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.errors = 0
         self.warmups = 0
         # keys already compiled by THIS service: repeat warmups (pool
         # re-warm after restore, overlapping cap lists) skip them —
@@ -807,8 +829,7 @@ class CompileService:
                     for leaf in jax.tree_util.tree_leaves(args))
                 out = fn(*args)
                 jax.block_until_ready(out)
-            except Exception as e:  # noqa: BLE001 — warmup is best-effort:
-                # a failed spec falls back to lazy compile on first chunk
+            except Exception as e:  # noqa: BLE001 — collected, raised below
                 errors.append({"step": spec.key,
                                "error": f"{type(e).__name__}: {e}"})
                 return
@@ -822,10 +843,6 @@ class CompileService:
         if specs:
             with ThreadPoolExecutor(max_workers=nworkers) as pool:
                 list(pool.map(run, specs))
-        if errors:
-            logging.getLogger("siddhi_tpu.compile").warning(
-                "app '%s': %d warmup spec(s) failed and will compile "
-                "lazily: %s", self.app.name, len(errors), errors[:3])
         wall = time.perf_counter() - t0
         after = cache_counts()
         n_sharded = sum(1 for r in records if r.get("sharded"))
@@ -852,7 +869,12 @@ class CompileService:
             self.total_ms += result["compile_ms"]
             self.cache_hits += result["cache_hits"]
             self.cache_misses += result["cache_misses"]
+            self.errors += len(errors)
             self.records.extend(records)
+        if errors:
+            # a step that fails to compile at warmup fails on its first
+            # chunk too: report it here, never defer it to traffic
+            raise WarmupError(self.app.name, errors)
         return result
 
     def summary(self, detail: bool = False) -> dict:
@@ -864,6 +886,7 @@ class CompileService:
                 "compile_ms": round(self.total_ms, 1),
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
+                "errors": self.errors,
             }
             if self.audit is not None:
                 out["audit"] = dict(self.audit)

@@ -37,10 +37,10 @@ Samples accumulate into registry histograms
 reporters see the same numbers, and ``runtime.cost_report()`` rolls
 them up into a ranked table (ms/event, share of total, queue-depth
 trend -> bottleneck verdict). ``runtime.cost_save()`` persists the
-table to ``<SIDDHI_TPU_CACHE_DIR>/costs.json`` next to the persistent
-compile cache, keyed ``<kind>/<name>`` in the compile-spec key style —
-the measured per-segment costs ROADMAP item 5's cost-aware plan
-optimizer needs.
+table to ``<state_dir()>/costs.json`` (``$SIDDHI_TPU_STATE_DIR``, else
+``<checkout>/.siddhi_state``; never the compile cache), keyed
+``<kind>/<name>`` in the compile-spec key style — the measured
+per-segment costs ROADMAP item 5's cost-aware plan optimizer needs.
 """
 from __future__ import annotations
 
@@ -63,9 +63,21 @@ SAMPLE_CAP = 2048
 QUEUE_CAP = 64
 
 
+STATE_DIR_ENV = "SIDDHI_TPU_STATE_DIR"
+
+
+def state_dir() -> str:
+    """Where measured run state lives — the cost table and SLO flight
+    records: $SIDDHI_TPU_STATE_DIR, else <checkout>/.siddhi_state. Kept
+    apart from the compile cache: a cache directory shared between runs
+    must not carry one run's cost table into another run's plan."""
+    return os.environ.get(STATE_DIR_ENV) or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".siddhi_state")
+
+
 def default_costs_path() -> str:
-    cache = os.environ.get("SIDDHI_TPU_CACHE_DIR") or "./.jax_cache"
-    return os.path.join(cache, "costs.json")
+    return os.path.join(state_dir(), "costs.json")
 
 
 class _Probe:

@@ -43,7 +43,7 @@ The **flight recorder** is a bounded ring of recent spans, admission
 rejections and state transitions; entering PAGE (or an explicit caller
 trigger: deploy failure, chaos-scenario failure) dumps the ring plus a
 context snapshot as a JSON artifact under
-``<SIDDHI_TPU_CACHE_DIR>/flightrec/`` so the breach is diagnosable
+``<state_dir()>/flightrec/`` (obs/costmodel.py) so the breach is diagnosable
 after the fact. See docs/observability.md "SLO engine".
 """
 from __future__ import annotations
@@ -104,12 +104,10 @@ def _time_ms(value, role: str) -> float:
 
 def default_flight_dir() -> str:
     """Artifact directory: SIDDHI_TPU_FLIGHT_DIR, else ``flightrec/``
-    next to the persistent compile cache (costs.json's neighborhood)."""
-    d = os.environ.get(FLIGHT_DIR_ENV)
-    if d:
-        return d
-    cache = os.environ.get("SIDDHI_TPU_CACHE_DIR") or "./.jax_cache"
-    return os.path.join(cache, "flightrec")
+    under the run-state directory (costs.json's neighborhood)."""
+    from .costmodel import state_dir
+    return os.environ.get(FLIGHT_DIR_ENV) or os.path.join(
+        state_dir(), "flightrec")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -44,7 +44,7 @@ from ..core.types import AttrType, NUMERIC_TYPES, np_dtype, promote
 from ..lang import ast as A
 from .expr import (Col, CompileError, CompiledExpr, Scope, compile_expression,
                    env_from_batch)
-from .keyed import (cumsum_fast, hash_columns, lookup_or_insert,
+from .keyed import (cummax_fast, cumsum_fast, hash_columns, lookup_or_insert,
                     segmented_cummax, segmented_cummin, segmented_cumsum)
 from .operators import Operator
 from .selector import (AGGREGATOR_NAMES, compile_order_by, const_int,
@@ -441,12 +441,12 @@ class UnionSetAgg(AggSpec):
         c_s = all_sgn[order]
         seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_),
                                      v_s[1:] != v_s[:-1]])
-        seg_id = jnp.cumsum(seg_start.astype(jnp.int64)) - 1
+        seg_id = cumsum_fast(seg_start.astype(jnp.int64)) - 1
         totals = segmented_cumsum(c_s, seg_id)
         is_last = jnp.concatenate([seg_id[:-1] != seg_id[1:],
                                    jnp.ones((1,), jnp.bool_)])
         live = is_last & (totals > 0) & (v_s != SET_EMPTY)
-        rank = jnp.cumsum(live.astype(jnp.int64)) - 1
+        rank = cumsum_fast(live.astype(jnp.int64)) - 1
         n_live = jnp.sum(live.astype(jnp.int64))
         dest = jnp.where(live & (rank < S), rank, jnp.int64(S))
         new_vals = jnp.full((S,), jnp.int64(SET_EMPTY)).at[dest].set(
@@ -952,7 +952,7 @@ class AggregateOp(Operator):
             # a new chunk starts at the first valid row or where an
             # EXPIRED/RESET row follows a CURRENT row.
             vidx = jnp.where(valid, rows, jnp.int64(-1))
-            last_valid_upto = jax.lax.cummax(vidx)
+            last_valid_upto = cummax_fast(vidx)
             prev_valid = jnp.concatenate([jnp.full((1,), -1, jnp.int64),
                                           last_valid_upto[:-1]])
             prev_kind = jnp.where(

@@ -55,6 +55,7 @@ from ..core.event import (CURRENT, EXPIRED, RESET, Attribute, EventBatch,
 from ..core.types import AttrType, NUMERIC_TYPES, np_dtype, promote
 from ..lang import ast as A
 from .expr import Col, CompileError, CompiledExpr, Scope, compile_expression
+from .keyed import cumsum_fast
 from .table import band_bounds, sorted_key_view
 
 from .sentinels import POS_INF
@@ -491,8 +492,8 @@ class JoinCross:
             # candidates beyond CAND were never evaluated: counted as
             # dropped (never silent; size @cap(join.candidates) up)
             cand_lost = jnp.maximum(ctotal - CAND, 0)
-            S = jnp.cumsum(s.astype(jnp.int64))           # surv ranks
-            soffs = jnp.cumsum(surv)                      # [B] inclusive
+            S = cumsum_fast(s.astype(jnp.int64))          # surv ranks
+            soffs = cumsum_fast(surv)                     # [B] inclusive
         else:
             surv = cnt
             cand_lost = jnp.int64(0)

@@ -44,8 +44,8 @@ from ..core.event import CURRENT, EXPIRED, RESET, TIMER, EventBatch, \
     StreamSchema
 from ..core.types import AttrType, np_dtype
 from .expr import CompileError
-from .keyed import (cumsum_fast, hash_columns, lookup_or_insert,
-                    segmented_cumsum)
+from .keyed import (cummax_fast, cumsum_fast, hash_columns,
+                    lookup_or_insert, segmented_cumsum)
 from .windows import (I32_MAX, NEG_INF, POS_INF, WindowOp, arrival_seqs,
                       current_row_positions, empty_buffer, emission_sort,
                       keep_newest, make_pool, running_time)
@@ -56,7 +56,7 @@ def _ext_running_time(batch: EventBatch, ts_idx: int):
     valid CURRENT rows."""
     e = batch.cols[ts_idx].astype(jnp.int64)
     e = jnp.where(batch.valid & (batch.kind == CURRENT), e, NEG_INF)
-    return jax.lax.cummax(e)
+    return cummax_fast(e)
 
 
 class ExternalTimeWindowOp(WindowOp):
@@ -980,7 +980,7 @@ class ExternalTimeBatchWindowOp(WindowOp):
         # group-first = w differs from the previous valid row's w
         pidx = jnp.where(pool["valid"], jnp.arange(P), -1)
         prev_idx = jnp.concatenate([jnp.full((1,), -1),
-                                    jax.lax.cummax(pidx)[:-1]])
+                                    cummax_fast(pidx)[:-1]])
         prev_w = jnp.where(prev_idx >= 0, w_of[jnp.clip(prev_idx, 0)],
                            jnp.int64(-2 ** 62))
         grp_first = pool["valid"] & (w_of != prev_w)
@@ -1204,7 +1204,7 @@ class SessionWindowOp(WindowOp):
         grp_break = slot_first | boundary
         sid = segmented_cumsum(grp_break.astype(jnp.int64), s_slot) - 1
         # does the slot's first in-step session extend the carried one?
-        fidx = jax.lax.cummax(jnp.where(slot_first, jnp.arange(B), -1))
+        fidx = cummax_fast(jnp.where(slot_first, jnp.arange(B), -1))
         first_cont = slot_first & ~boundary
         cont = first_cont[jnp.clip(fidx, 0)] & (fidx >= 0)
         joins_carried = s_valid & (sid == 0) & cont
@@ -1214,7 +1214,7 @@ class SessionWindowOp(WindowOp):
         is_last = jnp.concatenate([
             seg_key[:-1] != seg_key[1:],
             jnp.ones((1,), jnp.bool_)]) & s_valid
-        last_ts_rev = jax.lax.cummax(
+        last_ts_rev = cummax_fast(
             jnp.where(is_last, s_ts, NEG_INF)[::-1])[::-1]
         close_ts_sorted = jnp.where(s_valid, last_ts_rev + gap, POS_INF)
         closes_sorted = close_ts_sorted <= rt_max

@@ -432,7 +432,7 @@ def _timed(fn) -> float:
     return time.perf_counter() - t0
 
 
-def measure_scaling(n_devices: int = 8, chunk: int = 16384,
+def measure_scaling(n_devices: int = 4, chunk: int = 16384,
                     seq_chunk: Optional[int] = None,
                     iters: int = 4, reps: int = 2,
                     tenants: Optional[int] = None,
@@ -441,13 +441,12 @@ def measure_scaling(n_devices: int = 8, chunk: int = 16384,
     """The MULTICHIP acceptance measurement: aggregate events/s for each
     arm at n_devices vs 1 device (weak scaling — per-device load held
     constant), with per-arm scaling efficiency. Returns the JSON-ready
-    dict bench.py `multichip` and the __graft_entry__ child both emit.
+    dict bench.py `multichip` and __graft_entry__ both emit.
 
-    `platform` makes the artifact honest about WHERE it ran: on the
-    forced-host-device CPU shim every "device" shares the host's cores
-    (one core: no scaling is physically possible — the numbers guard
-    plumbing, not parallelism); on real multi-chip hardware the
-    efficiency number is the ROADMAP item 1 acceptance signal."""
+    `platform`, `device_kind` and `device_count` say WHERE it ran: on
+    forced CPU host devices every "device" shares the host's cores, so
+    the numbers guard plumbing, not parallelism, and are never a device
+    metric."""
     if len(jax.devices()) < n_devices:
         raise RuntimeError(
             f"measure_scaling wants {n_devices} devices, "
@@ -459,7 +458,8 @@ def measure_scaling(n_devices: int = 8, chunk: int = 16384,
     out: dict = {
         "n_devices": n_devices,
         "platform": jax.devices()[0].platform,
-        "host_device_shim": jax.devices()[0].platform == "cpu",
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "arms": {},
     }
     if "filter" in arms:

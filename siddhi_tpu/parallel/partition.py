@@ -307,13 +307,17 @@ class PartitionBlockRuntime:
                           dtype=jnp.asarray(x).dtype),
             state)
 
-    def _apply_mesh_sharding(self, qstates=None, slot_tbl=None):
+    def _apply_mesh_sharding(self, qstates=None, slot_tbl=None,
+                             emitted=None, lost=None):
         """Place the block state per the regex rule table
         (parallel/sharding.py PARTITION_STATE_RULES): [K]-leading
         qstates shard over the mesh's first axis — XLA then partitions
         the slot-vmap across devices (each device owns K/n key slots —
         GSPMD routing, see module docstring) — and the key-slot table
-        replicates. Accepts HOST pytrees (restore): a sharded
+        and the emitted/lost counters replicate, so the first dispatch
+        already runs the steady-state program (a step returns them
+        replicated; a device-0 counter would compile a second variant
+        on the next chunk). Accepts HOST pytrees (restore): a sharded
         `device_put` of a numpy leaf is ONE placement that never
         aliases the payload, so restore re-places shards directly
         instead of a fresh-copy-then-re-place double transfer
@@ -323,10 +327,14 @@ class PartitionBlockRuntime:
         placed = sharding.shard_pytree(
             {"qstates": qstates if qstates is not None else self.qstates,
              "slot_tbl": slot_tbl if slot_tbl is not None
-             else self.slot_tbl},
+             else self.slot_tbl,
+             "emitted": emitted if emitted is not None else self._emitted,
+             "lost": lost if lost is not None else self._lost},
             self.mesh, sharding.PARTITION_STATE_RULES)
         self.qstates = placed["qstates"]
         self.slot_tbl = placed["slot_tbl"]
+        self._emitted = placed["emitted"]
+        self._lost = placed["lost"]
 
     # -- key computation --------------------------------------------------
     def _slots_for(self, spec, batch: EventBatch, now, slot_tbl):
@@ -577,7 +585,9 @@ class PartitionBlockRuntime:
                 # guard is subsumed), never a fresh single-device copy
                 # that a second pass then re-places
                 self._apply_mesh_sharding(qstates=snap["qstates"],
-                                          slot_tbl=snap["slot_tbl"])
+                                          slot_tbl=snap["slot_tbl"],
+                                          emitted=snap["emitted"],
+                                          lost=snap["lost"])
             else:
                 # snapshot payloads are host numpy; device_put may
                 # alias them zero-copy, so single-device restores route
@@ -585,10 +595,10 @@ class PartitionBlockRuntime:
                 # donated step (core/runtime.py)
                 self.slot_tbl = _fresh_device(snap["slot_tbl"])
                 self.qstates = _fresh_device(snap["qstates"])
-            self._emitted = {k: jnp.array(v, copy=True)
-                             for k, v in snap["emitted"].items()}
-            self._lost = {k: jnp.array(v, copy=True)
-                          for k, v in snap["lost"].items()}
+                self._emitted = {k: jnp.array(v, copy=True)
+                                 for k, v in snap["emitted"].items()}
+                self._lost = {k: jnp.array(v, copy=True)
+                              for k, v in snap["lost"].items()}
             for qn in self._sched_due:
                 self._sched_due[qn] = None
             for qn, rsnap in snap.get("rate", {}).items():

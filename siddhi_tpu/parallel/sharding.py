@@ -239,30 +239,18 @@ def place_leading(arr, mesh: Mesh, axis=None):
 def build_mesh(n_devices=None, axis: str = "shards",
                devices=None) -> Mesh:
     """A 1-D mesh over the first ``n_devices`` local devices (default:
-    all of them). The CPU shim (`XLA_FLAGS=--xla_force_host_platform_
-    device_count=N`) makes this testable without hardware."""
+    all of them). Tests get N virtual CPU devices from
+    `JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N`."""
     devs = list(devices if devices is not None else jax.devices())
     n = int(n_devices) if n_devices else len(devs)
     if len(devs) < n:
         raise ValueError(
-            f"mesh wants {n} devices but only {len(devs)} are visible "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n} for the CPU shim)")
+            f"mesh wants {n} devices but only {len(devs)} are visible")
     return Mesh(np.array(devs[:n]), (axis,))
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map: jax >= 0.8 exports it at the top
-    level (check_vma), older versions under jax.experimental
-    (check_rep). Replication checking is off either way — the local
-    steps intentionally mix sharded state with replicated clocks."""
-    try:
-        from jax import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sme
-
-        return _sme(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off — the local steps
+    intentionally mix sharded state with replicated clocks."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

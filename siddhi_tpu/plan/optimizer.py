@@ -37,7 +37,7 @@ machine-readable decisions with cause slugs in
    per-query ``emitted`` counters then count the pruned stream
    (documented in docs/performance.md).
 4. **Cost-driven selection** (``SIDDHI_TPU_OPT_COST=0``): the measured
-   PR 7 cost table (``.jax_cache/costs.json``) is consulted through the
+   PR 7 cost table (``<state dir>/costs.json``) is consulted through the
    staleness guard (obs/costmodel.load_costs_for): a measured
    ``fanout/<junction>`` center slower per event than the sum of its
    members declines the fusion (``cost-evidence-unfused``), and

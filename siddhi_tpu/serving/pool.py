@@ -1209,6 +1209,15 @@ class TenantPool:
             return ("b", self._stacked_batch(per_slot, cap, sid))
         return self._pack_round(sid, per_slot, cap)
 
+    def _encoder(self, sid: str):
+        """The stream's sticky packed encoder (created on first use)."""
+        from ..core.ingest import PackedEncoder
+        enc_ = self._encoders.get(sid)
+        if enc_ is None:
+            schema = self.proto.junctions[sid].schema
+            enc_ = self._encoders[sid] = PackedEncoder(schema)
+        return enc_
+
     def _pack_round(self, sid: str, per_slot: dict, cap: int):
         """Pack one ingest stream's round into ONE (slots, total) uint8
         buffer (core/ingest.py wire format, one row per slot) and ship
@@ -1222,11 +1231,8 @@ class TenantPool:
         all-zero except the `now` header slot: every row carries the
         round clock, so idle tenants' windows expire on the same clock
         as active ones (the batch flavor's global `now` twin)."""
-        from ..core.ingest import PackedEncoder, layout
-        enc_ = self._encoders.get(sid)
-        if enc_ is None:
-            schema = self.proto.junctions[sid].schema
-            enc_ = self._encoders[sid] = PackedEncoder(schema)
+        from ..core.ingest import layout
+        enc_ = self._encoder(sid)
         chunks = list(per_slot.values())
         enc = enc_.widen_round(chunks)
         _H, _offs, total = layout(len(enc_.schema.types), enc, cap)
@@ -1725,7 +1731,8 @@ class TenantPool:
                     f"{base}/{qname}/timer/v{slots}", tbuild))
         return specs
 
-    def warmup(self, caps=None, workers: Optional[int] = None) -> dict:
+    def warmup(self, caps=None, workers: Optional[int] = None,
+               samples: Optional[dict] = None) -> dict:
         """Compile the pool's vmapped step programs through the
         prototype's PR 5 CompileService (parallel lowering + persistent
         cache + telemetry) BEFORE the first tenant's traffic: telemetry
@@ -1733,7 +1740,12 @@ class TenantPool:
         how many tenants deploy. Specs are keyed by template content
         (not pool name) and the service skips keys it already compiled,
         so re-warms with overlapping cap lists lower only the NEW
-        shapes."""
+        shapes. ``samples`` maps ingest stream ids to a ``(ts, cols)``
+        traffic sample (runtime.warmup's contract): the stream's sticky
+        packed encoding widens over it first, as the first round would,
+        so the packed program traffic dispatches is the one compiled."""
+        for sid, sample in (samples or {}).items():
+            self._encoder(self._resolve_stream(sid)).widen_round([sample])
         result = self.proto.compile_service.warm_specs(
             self._warm_spec_list(caps), workers=workers)
         self._warmed = True

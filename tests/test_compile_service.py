@@ -225,8 +225,6 @@ def test_warm_start_in_process_hits_persistent_cache(tmp_path):
 
 _CHILD_SCRIPT = r"""
 import json
-import jax
-jax.config.update("jax_platforms", "cpu")
 from siddhi_tpu import SiddhiManager
 APP = '''
 @app:playback
@@ -245,10 +243,10 @@ print(json.dumps({k: wu[k] for k in
 
 @pytest.mark.slow
 def test_warm_start_across_processes(tmp_path):
-    """Two subprocesses sharing SIDDHI_TPU_CACHE_DIR: the second run
-    reports cache hits > 0 and compiles strictly fewer programs."""
+    """Two subprocesses sharing JAX_COMPILATION_CACHE_DIR: the second
+    run reports cache hits > 0 and compiles strictly fewer programs."""
     env = dict(os.environ)
-    env.update(SIDDHI_TPU_CACHE_DIR=str(tmp_path), JAX_PLATFORMS="cpu")
+    env.update(JAX_COMPILATION_CACHE_DIR=str(tmp_path), JAX_PLATFORMS="cpu")
 
     def run():
         proc = subprocess.run(
@@ -262,3 +260,43 @@ def test_warm_start_across_processes(tmp_path):
     assert cold["cache_misses"] > 0
     assert warm["cache_hits"] > 0, (cold, warm)
     assert warm["cache_misses"] < cold["cache_misses"], (cold, warm)
+
+
+def test_failed_warmup_spec_raises_not_deferred():
+    """A spec that fails at warmup raises WarmupError once every spec was
+    tried — it is never left to compile lazily on the first chunk."""
+    from siddhi_tpu.core.compile import CompileSpec, WarmupError
+
+    def bad():
+        raise ValueError("cannot build")
+
+    rt = SiddhiManager().create_siddhi_app_runtime(CHAIN_APP)
+    with pytest.raises(WarmupError) as ei:
+        rt.compile_service.warm_specs([CompileSpec("bad/row/16", bad)])
+    assert ei.value.errors[0]["step"] == "bad/row/16"
+    assert rt.compile_service.summary()["errors"] == 1
+    assert rt.compile_service.ready
+    rt.shutdown()
+
+
+_CACHE_DIR_SCRIPT = (
+    "import json, jax, siddhi_tpu\n"
+    "print(json.dumps(jax.config.jax_compilation_cache_dir))\n")
+
+
+@pytest.mark.parametrize("given", [False, True])
+def test_compile_cache_is_placed_from_outside(tmp_path, given):
+    """JAX_COMPILATION_CACHE_DIR set: the code names no other directory.
+    Unset: <checkout>/.jax_cache, whatever the working directory."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if given:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "given")
+    proc = subprocess.run([sys.executable, "-c", _CACHE_DIR_SCRIPT],
+                          cwd=str(tmp_path), env=env, capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = json.loads(proc.stdout.splitlines()[-1])
+    assert got == (str(tmp_path / "given") if given
+                   else os.path.join(repo, ".jax_cache"))

@@ -458,14 +458,14 @@ class TestProfileReportTool:
 
     def test_config_filter_human_report(self, capsys, tmp_path,
                                         monkeypatch):
-        monkeypatch.setenv("SIDDHI_TPU_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("SIDDHI_TPU_STATE_DIR", str(tmp_path))
         rc, out = self._main(["--config", "filter", "--events", "1024",
                               "--chunk", "512"], capsys)
         assert rc == 0
         assert "pipeline cost report" in out
         assert "query/q" in out
         assert "bottleneck:" in out
-        # the persisted table landed next to the compile cache
+        # the persisted table landed in the run-state directory
         from siddhi_tpu.obs.costmodel import load_costs
         table = load_costs(str(tmp_path / "costs.json"))
         assert any("query/q" in centers for centers in table.values())
@@ -484,3 +484,23 @@ class TestProfileReportTool:
         report = json.loads(out)
         assert report["app"] == "cost_probe"
         assert any(s["step"] == "query/q" for s in report["steps"])
+
+
+def test_cost_table_and_flight_records_live_outside_the_compile_cache(
+        monkeypatch):
+    """The plan optimizer reads the cost table: it must not ride in a
+    compile-cache directory that other runs share."""
+    import os
+
+    import jax
+    from siddhi_tpu.obs.costmodel import default_costs_path, state_dir
+    from siddhi_tpu.obs.slo import default_flight_dir
+    monkeypatch.delenv("SIDDHI_TPU_STATE_DIR", raising=False)
+    monkeypatch.delenv("SIDDHI_TPU_FLIGHT_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert state_dir() == os.path.join(repo, ".siddhi_state")
+    cache = os.path.abspath(jax.config.jax_compilation_cache_dir)
+    for p in (default_costs_path(), default_flight_dir()):
+        assert os.path.commonpath([cache, os.path.abspath(p)]) != cache
+    monkeypatch.setenv("SIDDHI_TPU_STATE_DIR", "/elsewhere")
+    assert default_costs_path() == os.path.join("/elsewhere", "costs.json")

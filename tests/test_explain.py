@@ -250,7 +250,7 @@ class TestGroundTruth:
                                              monkeypatch):
         # a persisted cost table showing this join's GRID center
         # dominating flips the recorded cause to evidence-backed
-        monkeypatch.setenv("SIDDHI_TPU_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("SIDDHI_TPU_STATE_DIR", str(tmp_path))
         (tmp_path / "costs.json").write_text(json.dumps(
             {"xp_join": {"join/q.left[grid]": {"ms_total": 99.0},
                          "query/other": {"ms_total": 1.0}}}))

@@ -332,7 +332,7 @@ def test_partition_restore_places_each_leaf_once(monkeypatch):
     blk = next(iter(rt.partitions.values()))
     snap = blk.snapshot_state()
     n_leaves = len(jax.tree_util.tree_leaves(
-        {"qstates": snap["qstates"], "slot_tbl": snap["slot_tbl"]}))
+        {k: snap[k] for k in ("qstates", "slot_tbl", "emitted", "lost")}))
 
     real_put = jax.device_put
     puts = [0]
@@ -568,14 +568,14 @@ def test_pool_mesh_snapshot_restore_isolated():
     pool.shutdown()
 
 
-# ---- the forced-device subprocess shim (bench/dryrun child path) ------
+# ---- forced CPU devices through the environment alone -------------------
 
 
 def test_forced_device_shim_subprocess():
-    """The exact env the bench `multichip` child and the dryrun child
-    run under: a clean subprocess with forced host devices must see 8
-    devices and place a sharded pytree (guards the rc=124/empty-tail
-    class before hardware rounds)."""
+    """A clean subprocess given forced host devices through its
+    environment only (JAX_PLATFORMS + XLA_FLAGS, no config re-pin) sees
+    8 devices and places a sharded pytree — the env a CPU run of the
+    multichip bench config gets."""
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
@@ -584,7 +584,6 @@ def test_forced_device_shim_subprocess():
     env["JAX_PLATFORMS"] = "cpu"
     code = (
         "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
         "import numpy as np\n"
         "import siddhi_tpu\n"
         "from siddhi_tpu.parallel import sharding\n"

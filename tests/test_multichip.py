@@ -17,6 +17,6 @@ import __graft_entry__ as graft
 def test_dryrun_multichip_group_by_and_pattern():
     assert len(jax.devices()) == 8
     # bench=False: the equivalence sweep only — the measured scaling
-    # arms (MULTICHIP_r* artifact) do not fit the tier-1 budget and are
+    # arms do not fit the tier-1 budget and are
     # guarded by tests/test_bench_smoke.py::test_bench_multichip instead
     graft._dryrun_multichip_impl(8, bench=False)

@@ -303,8 +303,8 @@ define stream S (sym string, v int, p float);
 
 def _deploy_cost(tmp_path, table=None):
     tmp_path.mkdir(parents=True, exist_ok=True)
-    prev = os.environ.get("SIDDHI_TPU_CACHE_DIR")
-    os.environ["SIDDHI_TPU_CACHE_DIR"] = str(tmp_path)
+    prev = os.environ.get("SIDDHI_TPU_STATE_DIR")
+    os.environ["SIDDHI_TPU_STATE_DIR"] = str(tmp_path)
     try:
         if table is not None:
             (tmp_path / "costs.json").write_text(json.dumps(table))
@@ -316,9 +316,9 @@ def _deploy_cost(tmp_path, table=None):
         return rep, stats
     finally:
         if prev is None:
-            os.environ.pop("SIDDHI_TPU_CACHE_DIR", None)
+            os.environ.pop("SIDDHI_TPU_STATE_DIR", None)
         else:
-            os.environ["SIDDHI_TPU_CACHE_DIR"] = prev
+            os.environ["SIDDHI_TPU_STATE_DIR"] = prev
 
 
 def _cost_entry(mpe):
@@ -559,7 +559,7 @@ def test_explain_expect_golden(name, tmp_path):
     app = GOLDEN_DIR / f"{name}.siddhi"
     golden = GOLDEN_DIR / f"{name}.expect.json"
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               SIDDHI_TPU_CACHE_DIR=str(tmp_path))  # no local costs.json
+               SIDDHI_TPU_STATE_DIR=str(tmp_path))  # no local costs.json
     proc = subprocess.run(
         [sys.executable, str(TOOLS / "explain.py"), str(app),
          "--expect", str(golden)],

@@ -270,14 +270,16 @@ def test_shipped_audit_baseline_is_empty():
 
 
 def test_repo_suite_audits_clean_within_budget():
+    # runtime CONSTRUCTION compiles small eager fill programs (persistent
+    # cache misses when the cache is cold); a first pass warms those, so
+    # the measured pass isolates the audit, which must compile nothing
+    _cli(str(SUITE))
     before = compile_mod.cache_counts()
     t0 = time.monotonic()
     code, text = _cli(str(SUITE))
     elapsed = time.monotonic() - t0
     assert code == 0, text
     assert "0 new finding(s)" in text
-    # runtime CONSTRUCTION touches the cache (hits); the audit itself
-    # must compile nothing — zero new cache entries
     after = compile_mod.cache_counts()
     assert after["misses"] == before["misses"], \
         "suite audit compiled new programs"
@@ -320,7 +322,7 @@ def test_corpus_slice_audits_clean_within_budget(monkeypatch):
     from siddhi_tpu.analysis.programs import audit_runtime
     from siddhi_tpu.lang.tokens import SiddhiParserException
     from siddhi_tpu.ops.expr import CompileError
-    before = compile_mod.cache_counts()
+    audit_misses = 0
     gets = [0]
     real_get = jax.device_get
 
@@ -339,7 +341,9 @@ def test_corpus_slice_audits_clean_within_budget(monkeypatch):
             rt = mgr.create_siddhi_app_runtime(text)
         except (CompileError, SiddhiParserException):
             continue
+        m0 = compile_mod.cache_counts()["misses"]
         rep = audit_runtime(rt, buckets=(1024,), path=rel, store=False)
+        audit_misses += compile_mod.cache_counts()["misses"] - m0
         dirty += [f"{rel}: {f.render()}" for f in rep.findings]
         audited += 1
     monkeypatch.undo()
@@ -348,10 +352,9 @@ def test_corpus_slice_audits_clean_within_budget(monkeypatch):
     assert audited >= 3, f"slice covered only {audited} apps"
     assert elapsed < 15.0, f"corpus slice took {elapsed:.1f}s"
     assert gets[0] == 0, "audit performed device reads"
-    # runtime CONSTRUCTION touches the cache (hits); the audit itself
-    # must compile nothing — zero new cache entries
-    assert compile_mod.cache_counts()["misses"] == before["misses"], \
-        "corpus audit compiled new programs"
+    # runtime CONSTRUCTION may compile eager fills (misses on a cold
+    # cache); the audit itself must compile nothing
+    assert audit_misses == 0, "corpus audit compiled new programs"
 
 
 @pytest.mark.slow

@@ -33,8 +33,6 @@ import sys
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
-os.environ.setdefault(
-    "SIDDHI_TPU_CACHE_DIR", os.path.join(REPO_ROOT, ".jax_cache"))
 
 DEMO_APP = """
 @app:name('explain_demo')

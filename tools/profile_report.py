@@ -18,7 +18,7 @@ DAG optimizer / kernel work should attack first (the profile -> rank ->
 optimize workflow in docs/performance.md).
 
 Side effects: merges the measured cost table into
-``<SIDDHI_TPU_CACHE_DIR>/costs.json`` (``--no-save`` to skip) and, with
+``<state dir>/costs.json`` (``--no-save`` to skip) and, with
 ``--trace PATH``, writes a Chrome trace whose spans carry the measured
 device-time annotations (``rt.trace_export``).
 
@@ -41,10 +41,6 @@ import numpy as np
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
-# share bench.py's repo-local persistent compile cache: repeat profiling
-# runs skip the compile phase entirely (docs/compile_cache.md)
-os.environ.setdefault(
-    "SIDDHI_TPU_CACHE_DIR", os.path.join(REPO_ROOT, ".jax_cache"))
 
 TS0 = 1_700_000_000_000
 SYMS = ("IBM", "WSO2", "GOOG", "MSFT")
