@@ -215,7 +215,7 @@ def rows_from_batch(schema_types: Sequence[AttrType], batch) -> list:
     uuid() sentinel cells materialize here with a per-decode nonce in the
     key: unique across batches, stable within one decode. Callers that
     deliver one emission to several consumers decode once and share the
-    rows (QueryRuntime._dispatch_output.rows_once)."""
+    rows (OutputFetch.rows)."""
     nonce = next(_UUID_BATCH_NONCE)
     ts = np.asarray(batch.ts)
     kind = np.asarray(batch.kind)
@@ -244,3 +244,69 @@ def rows_from_batch(schema_types: Sequence[AttrType], batch) -> list:
                 vals.append(int(cols[i][r]))
         out.append((int(ts[r]), int(kind[r]), tuple(vals)))
     return out
+
+
+class OutputFetch:
+    """One step output's way to host rows, each part at most once per
+    output: the host's wait for the device, the device-to-host copy and
+    the row decode. Every delivery path of an emission (debugger, rate
+    limiter, handlers, callbacks) shares one copy and one decoded row
+    list, so uuid() cells agree across paths.
+
+    While ``tracer`` records, the parts are the spans ``wait/<name>``,
+    ``d2h/<name>`` (args ``capacity``, ``bytes``) and ``decode/<name>``
+    (args ``rows``, the valid rows out). Otherwise this is one
+    ``jax.device_get`` and one decode, with no sync of its own.
+    ``decode`` turns the host batch into rows (``rows_from_batch``)."""
+
+    __slots__ = ("tracer", "name", "types", "out", "decode", "_host",
+                 "_rows")
+
+    def __init__(self, tracer, name: str, types: Sequence[AttrType], out,
+                 decode=rows_from_batch):
+        self.tracer = tracer if tracer is not None and tracer.enabled \
+            else None
+        self.name = name
+        self.types = types
+        self.out = out
+        self.decode = decode
+        self._host = None
+        self._rows = None
+
+    def host(self, due=None):
+        """The output on the host; with ``due``, ``(output, due)``, in
+        the same copy where the output has not crossed yet."""
+        if self._host is not None:
+            return self._host if due is None \
+                else (self._host, jax.device_get(due))
+        tree = self.out if due is None else (self.out, due)
+        tr = self.tracer
+        if tr is None:
+            got = jax.device_get(tree)
+        else:
+            with tr.span("wait", self.name):
+                jax.block_until_ready(tree)
+            nbytes = sum(int(x.nbytes)
+                         for x in jax.tree_util.tree_leaves(tree))
+            with tr.span("d2h", self.name, capacity=int(self.out.capacity),
+                         bytes=nbytes):
+                got = jax.device_get(tree)
+        self._host = got if due is None else got[0]
+        return got
+
+    def rows(self, shape=None) -> list:
+        """The output's valid rows, decoded once; ``shape`` (the query's
+        host-side order-by) is applied to them inside the decode span."""
+        if self._rows is not None and shape is None:
+            return self._rows
+        host = self.host()
+        if self.tracer is None:
+            return self._decode(host, shape)
+        with self.tracer.span("decode", self.name,
+                              rows=int(np.count_nonzero(host.valid))):
+            return self._decode(host, shape)
+
+    def _decode(self, host, shape):
+        if self._rows is None:
+            self._rows = self.decode(self.types, host)
+        return self._rows if shape is None else shape(self._rows)

@@ -60,8 +60,9 @@ from ..ops.windows2 import (BatchWindowOp, CronWindowOp, DelayWindowOp,
                             TimeLengthWindowOp)
 from ..ops.windows import (NEG_INF, POS_INF, LengthBatchWindowOp, LengthWindowOp,
                            TimeBatchWindowOp, TimeWindowOp, WindowOp)
-from .event import (CURRENT, EXPIRED, TIMER, Attribute, EventBatch, StreamSchema,
-                    batch_from_rows, rows_from_batch)
+from .event import (CURRENT, EXPIRED, TIMER, Attribute, EventBatch,
+                    OutputFetch, StreamSchema, batch_from_rows,
+                    rows_from_batch)
 from .ingest import PackedChunk, unpack_buffer
 from .scheduler import Scheduler
 from .stream import (Event, InputHandler, QueryCallback, Receiver,
@@ -200,19 +201,17 @@ def _as_current(batch: EventBatch) -> EventBatch:
 _rewrite_current = jax.jit(_as_current)
 
 
-def _chain_body(ops, has_timers: bool):
-    """The traced body of one query's operator chain:
+def _chain_body(ops, has_timers: bool, name: str):
+    """The traced body of query ``name``'s operator chain:
     (states, tstates, emitted, batch, now) ->
     (states', tstates', emitted', out, due). Shared by the per-query
-    step compilers and the fused chain step."""
+    step compilers and the fused chain step. Each operator runs under
+    the named scope ``<name>/<OpClass>`` (obs/profiler.py)."""
 
     def chain(states, tstates, emitted, batch, now):
         new_states = []
         for op, st in zip(ops, states):
-            # op_scope is a nullcontext unless SIDDHI_TPU_PROFILE_SCOPES=1
-            # (named scopes change lowered HLO -> compile-cache keys;
-            # docs/observability.md)
-            with op_scope(type(op).__name__):
+            with op_scope(f"{name}/{type(op).__name__}"):
                 if op.needs_tables:
                     st, batch, tstates = op.step_tables(st, batch, now,
                                                         tstates)
@@ -508,8 +507,8 @@ class QueryRuntime(Receiver):
 
     # -- compile ---------------------------------------------------------
     def _make_step(self):
-        return jax.jit(_chain_body(self.operators, self._has_timers),
-                       **_donate(0, 1, 2))
+        return jax.jit(_chain_body(self.operators, self._has_timers,
+                                   self.name), **_donate(0, 1, 2))
 
     def _step_for(self, capacity: int) -> Callable:
         # one jit wrapper; XLA specializes per batch-capacity shape
@@ -535,7 +534,7 @@ class QueryRuntime(Receiver):
         fn = self._packed_steps.get((enc, capacity))
         if fn is None:
             fn = _build_packed_step(
-                _chain_body(self.operators, self._has_timers),
+                _chain_body(self.operators, self._has_timers, self.name),
                 self.in_schema, enc, capacity, self.max_step_capacity,
                 self.app._playback)
             self._packed_steps[(enc, capacity)] = fn
@@ -788,12 +787,12 @@ class QueryRuntime(Receiver):
         handler/callback delivery."""
         for cb in self.batch_callbacks:
             cb(out)
-        # transfer + decode at most ONCE per emission: every delivery path
-        # (debugger, rate limiter, handlers, callbacks) shares one host
-        # copy and one decoded row list, so uuid() cells agree across
-        # paths and no extra device->host reads happen
-        _host: list = []
-        _decoded: list = []
+        # transfer + decode at most ONCE per emission, shared by every
+        # delivery path (core/event.py OutputFetch); the decode is this
+        # module's rows_from_batch, looked up per emission
+        fetch = OutputFetch(self.app.tracer, self.name,
+                            self.out_schema.types, out,
+                            decode=rows_from_batch)
         _current: list = []
 
         def current_once():
@@ -804,17 +803,6 @@ class QueryRuntime(Receiver):
                 _current.append(_rewrite_current(out))
             return _current[0]
 
-        def host_once():
-            if not _host:
-                _host.append(jax.device_get(out))
-            return _host[0]
-
-        def rows_once():
-            if not _decoded:
-                _decoded.append(rows_from_batch(self.out_schema.types,
-                                                host_once()))
-            return _decoded[0]
-
         dbg = self.app.debugger
         if dbg is not None:
             from .debugger import QueryTerminal
@@ -822,33 +810,19 @@ class QueryRuntime(Receiver):
                 dbg.check_break_point(
                     self.name, QueryTerminal.OUT,
                     [Event(ts, vals, is_expired=(k == EXPIRED))
-                     for ts, k, vals in rows_once()])
+                     for ts, k, vals in fetch.rows()])
         if self.rate_limiter is not None:
             if due is not None:
-                if _host:
-                    due_host = jax.device_get(due)
-                else:
-                    out_host, due_host = jax.device_get((out, due))
-                    _host.append(out_host)
+                _, due_host = fetch.host(due)
                 self._schedule(int(due_host))
-            rows = rows_once()
+            rows = fetch.rows()
             if rows:
                 self.rate_limiter.process(timestamp, rows)
             return
         row_handlers = [h for h in self.output_handlers
                         if not h.handle_device_batch(
                             out, timestamp, current=current_once)]
-        decode = bool(row_handlers or self.callback_handler.callbacks)
-        if decode and due is not None:
-            if _host:
-                due_host = jax.device_get(due)
-            else:
-                out_host, due_host = jax.device_get((out, due))
-                _host.append(out_host)
-            self._schedule(int(due_host))
-        elif decode:
-            pass
-        else:
+        if not (row_handlers or self.callback_handler.callbacks):
             if due is not None:
                 # NO sync here: a blocking device->host read would stall
                 # the pipeline; start an async copy and resolve the int
@@ -856,10 +830,12 @@ class QueryRuntime(Receiver):
                 # (app._resolve_dues) — by then the copy has landed
                 self.app.defer_due(self, due)
             return
-        out_rows = rows_once()
-        if not out_rows:
+        if due is not None:
+            _, due_host = fetch.host(due)
+            self._schedule(int(due_host))
+        out_rows = fetch.rows(self._host_shape_rows)
+        if not fetch.rows():    # nothing decoded (before the order-by)
             return
-        out_rows = self._host_shape_rows(out_rows)
         # ingest->emit SLO mark (obs/slo.py): host rows for this query's
         # sinks/callbacks just materialized — the device_get above
         # already forced the sync, so the sample is honest. Fused
@@ -961,6 +937,7 @@ class FusedChain:
         self.head = self.queries[0]
         self.tail = self.queries[-1]
         self.name = "+".join(q.name for q in self.queries)
+        self.members = tuple(q.name for q in self.queries)  # span args
         self.table_deps = sorted({t for q in self.queries
                                   for t in q.table_deps})
         # execution schedule (plan/optimizer.py): member ops + per-member
@@ -987,7 +964,8 @@ class FusedChain:
                     _, mi, oi = entry
                     op = queries[mi].operators[oi]
                     st = new_states[mi][oi]
-                    with op_scope(type(op).__name__):
+                    with op_scope(
+                            f"{queries[mi].name}/{type(op).__name__}"):
                         if op.needs_tables:
                             st, cur, tstates = op.step_tables(
                                 st, cur, now, tstates)
@@ -1078,7 +1056,7 @@ class FusedChain:
         probe = cost.probe("chain", self.name, cap=chunk.capacity) \
             if cost.enabled else None
         with self.app.tracer.span("chain", self.name, rows=chunk.n,
-                                  members=[q.name for q in self.queries]):
+                                  members=self.members):
             lat = self.head._stats_mark(chunk.n)
             for q in self.queries:
                 q._last_now = max(q._last_now, chunk.last_ts)
@@ -1100,7 +1078,7 @@ class FusedChain:
         cost = self.app.cost
         probe = cost.probe("chain", self.name) if cost.enabled else None
         with self.app.tracer.span("chain", self.name,
-                                  members=[q.name for q in self.queries]):
+                                  members=self.members):
             if now is None:
                 now = self.app.current_time()
             lat = self.head._stats_lat()
@@ -1317,24 +1295,27 @@ class PatternQueryRuntime(QueryRuntime):
         cost = self.app.cost
         probe = cost.probe("pattern", f"{self.name}.{stream_id}") \
             if cost.enabled else None
-        self._last_now = max(self._last_now, chunk.last_ts)
-        with self._lock:
-            step = self._step_for_stream(stream_id,
-                                         (chunk.enc, chunk.capacity))
-            with self._table_locks():
-                tstates = {t: self.app.tables[t].state
-                           for t in self.table_deps}
-                (self.nfa_state, self.states, tstates, self._emitted_dev,
-                 out) = step(self.nfa_state, self.states, tstates,
-                             self._emitted_dev, chunk.buf)
-                for t in self.table_deps:
-                    self.app.tables[t].state = tstates[t]
-        if probe is not None:
-            # sampled branch only: the sync serializes the pipeline
-            jax.block_until_ready(out.valid)
-            probe.done(rows=chunk.n)
-        self._dispatch_output(out, chunk.last_ts)
-        self._schedule_absent()
+        with self.app.tracer.span("step", self.name, rows=chunk.n,
+                                  stream=stream_id):
+            self._last_now = max(self._last_now, chunk.last_ts)
+            with self._lock:
+                step = self._step_for_stream(stream_id,
+                                             (chunk.enc, chunk.capacity))
+                with self._table_locks():
+                    tstates = {t: self.app.tables[t].state
+                               for t in self.table_deps}
+                    (self.nfa_state, self.states, tstates,
+                     self._emitted_dev, out) = step(
+                        self.nfa_state, self.states, tstates,
+                        self._emitted_dev, chunk.buf)
+                    for t in self.table_deps:
+                        self.app.tables[t].state = tstates[t]
+            if probe is not None:
+                # sampled branch only: the sync serializes the pipeline
+                jax.block_until_ready(out.valid)
+                probe.done(rows=chunk.n)
+            self._dispatch_output(out, chunk.last_ts)
+            self._schedule_absent()
 
     def process_stream_events(self, stream_id: str, events) -> None:
         schema = self.app.schemas[stream_id]
@@ -1355,26 +1336,31 @@ class PatternQueryRuntime(QueryRuntime):
             if cost.enabled else None
         self._last_now = max(self._last_now, int(now_host))
         now = jnp.asarray(now_host, dtype=jnp.int64)
-        with self._lock:
-            step = self._step_for_stream(stream_id)
-            with self._table_locks():
-                tstates = {t: self.app.tables[t].state
-                           for t in self.table_deps}
-                (self.nfa_state, self.states, tstates, self._emitted_dev,
-                 out) = step(self.nfa_state, self.states, tstates,
-                             self._emitted_dev, batch, now)
-                for t in self.table_deps:
-                    self.app.tables[t].state = tstates[t]
-        if probe is not None:
-            # sampled branch only: the sync serializes the pipeline
-            jax.block_until_ready(out.valid)
-            probe.done(rows=int(batch.capacity))
-        self._dispatch_output(out, timestamp)
-        # arm the scheduler at the earliest live absent deadline so the
-        # pattern fires on clock advance even when no further events come
-        # (AbsentStreamPreStateProcessor's scheduler role); costs one
-        # device readback per step, only for has_absent engines
-        self._schedule_absent()
+        with self.app.tracer.span("step", self.name,
+                                  capacity=int(batch.capacity),
+                                  stream=stream_id):
+            with self._lock:
+                step = self._step_for_stream(stream_id)
+                with self._table_locks():
+                    tstates = {t: self.app.tables[t].state
+                               for t in self.table_deps}
+                    (self.nfa_state, self.states, tstates,
+                     self._emitted_dev, out) = step(
+                        self.nfa_state, self.states, tstates,
+                        self._emitted_dev, batch, now)
+                    for t in self.table_deps:
+                        self.app.tables[t].state = tstates[t]
+            if probe is not None:
+                # sampled branch only: the sync serializes the pipeline
+                jax.block_until_ready(out.valid)
+                probe.done(rows=int(batch.capacity))
+            self._dispatch_output(out, timestamp)
+            # arm the scheduler at the earliest live absent deadline so
+            # the pattern fires on clock advance even when no further
+            # events come (AbsentStreamPreStateProcessor's scheduler
+            # role); costs one device readback per step, only for
+            # has_absent engines
+            self._schedule_absent()
 
 
 class JoinStreamReceiver(Receiver):
@@ -1581,32 +1567,35 @@ class JoinQueryRuntime(QueryRuntime):
         cost = self.app.cost
         probe = cost.probe("join", self._side_center(side)) \
             if cost.enabled else None
-        self._last_now = max(self._last_now, chunk.last_ts)
-        with self._lock:
-            step = self._step_for_side(side, (chunk.enc, chunk.capacity))
-            with self._table_locks():
-                tstates = {t: self.app.tables[t].state
-                           for t in self.table_deps}
-                (my, sel, tstates, self._emitted_dev, out, lost,
-                 due) = step(self.side_states[side], self.side_states[opp],
-                             self.states, tstates, self._emitted_dev,
-                             chunk.buf)
-                for t in self.table_deps:
-                    self.app.tables[t].state = tstates[t]
-            self.side_states[side] = my
-            self.states = sel
-            self._overflow_dev = self._overflow_dev + lost
-        if probe is not None:
-            # sampled branch only: the sync serializes the pipeline
-            jax.block_until_ready(out.valid)
-            probe.done(rows=chunk.n)
-        if self._join_host_due and chunk.ts_min is not None:
-            self._dispatch_output(out, chunk.last_ts)
-            self._schedule(min(op.host_due_bound(chunk.ts_min)
-                               for op in self._join_timer_ops))
-            return
-        self._dispatch_output(out, chunk.last_ts,
-                              due=due if self._has_timers else None)
+        with self.app.tracer.span("step", self.name, rows=chunk.n,
+                                  side=side):
+            self._last_now = max(self._last_now, chunk.last_ts)
+            with self._lock:
+                step = self._step_for_side(side,
+                                           (chunk.enc, chunk.capacity))
+                with self._table_locks():
+                    tstates = {t: self.app.tables[t].state
+                               for t in self.table_deps}
+                    (my, sel, tstates, self._emitted_dev, out, lost,
+                     due) = step(self.side_states[side],
+                                 self.side_states[opp], self.states,
+                                 tstates, self._emitted_dev, chunk.buf)
+                    for t in self.table_deps:
+                        self.app.tables[t].state = tstates[t]
+                self.side_states[side] = my
+                self.states = sel
+                self._overflow_dev = self._overflow_dev + lost
+            if probe is not None:
+                # sampled branch only: the sync serializes the pipeline
+                jax.block_until_ready(out.valid)
+                probe.done(rows=chunk.n)
+            if self._join_host_due and chunk.ts_min is not None:
+                self._dispatch_output(out, chunk.last_ts)
+                self._schedule(min(op.host_due_bound(chunk.ts_min)
+                                   for op in self._join_timer_ops))
+                return
+            self._dispatch_output(out, chunk.last_ts,
+                                  due=due if self._has_timers else None)
 
     def process_side_events(self, side: str, events) -> None:
         for batch, last_ts in self.encode_chunks(self.in_schemas[side],
@@ -1623,41 +1612,45 @@ class JoinQueryRuntime(QueryRuntime):
                 self.process_side_batch(side, sub, timestamp, now=now,
                                         skip_due=skip_due)
             return
-        if batch.kind is not None and not bool(np.any(
-                np.asarray(batch.kind) == TIMER)):
-            # only EVENT steps advance the due-subsumption clock — timer
-            # fires must not suppress their own follow-up dues
-            self._last_now = max(self._last_now, int(timestamp))
-        if now is None:
-            now = self.app.current_time()
         cost = self.app.cost
         probe = cost.probe("join", self._side_center(side)) \
             if cost.enabled else None
-        now_dev = jnp.asarray(now, dtype=jnp.int64)
-        opp = "R" if side == "L" else "L"
-        with self._lock:
-            step = self._step_for_side(side)
-            with self._table_locks():
-                tstates = {t: self.app.tables[t].state
-                           for t in self.table_deps}
-                (my, sel, tstates, self._emitted_dev, out, lost,
-                 due) = step(self.side_states[side], self.side_states[opp],
-                             self.states, tstates, self._emitted_dev,
-                             batch, now_dev)
-                for t in self.table_deps:
-                    self.app.tables[t].state = tstates[t]
-            self.side_states[side] = my
-            self.states = sel
-            # join pairs beyond join_cap are dropped by JoinCross.cross —
-            # counted here, never silent (join.py design contract)
-            self._overflow_dev = self._overflow_dev + lost
-        if probe is not None:
-            # sampled branch only: the sync serializes the pipeline
-            jax.block_until_ready(out.valid)
-            probe.done(rows=int(batch.capacity))
-        self._dispatch_output(
-            out, timestamp,
-            due=due if (self._has_timers and not skip_due) else None)
+        with self.app.tracer.span("step", self.name,
+                                  capacity=int(batch.capacity), side=side):
+            if batch.kind is not None and not bool(np.any(
+                    np.asarray(batch.kind) == TIMER)):
+                # only EVENT steps advance the due-subsumption clock —
+                # timer fires must not suppress their own follow-up dues
+                self._last_now = max(self._last_now, int(timestamp))
+            if now is None:
+                now = self.app.current_time()
+            now_dev = jnp.asarray(now, dtype=jnp.int64)
+            opp = "R" if side == "L" else "L"
+            with self._lock:
+                step = self._step_for_side(side)
+                with self._table_locks():
+                    tstates = {t: self.app.tables[t].state
+                               for t in self.table_deps}
+                    (my, sel, tstates, self._emitted_dev, out, lost,
+                     due) = step(self.side_states[side],
+                                 self.side_states[opp], self.states,
+                                 tstates, self._emitted_dev, batch,
+                                 now_dev)
+                    for t in self.table_deps:
+                        self.app.tables[t].state = tstates[t]
+                self.side_states[side] = my
+                self.states = sel
+                # join pairs beyond join_cap are dropped by
+                # JoinCross.cross — counted here, never silent (join.py
+                # design contract)
+                self._overflow_dev = self._overflow_dev + lost
+            if probe is not None:
+                # sampled branch only: the sync serializes the pipeline
+                jax.block_until_ready(out.valid)
+                probe.done(rows=int(batch.capacity))
+            self._dispatch_output(
+                out, timestamp,
+                due=due if (self._has_timers and not skip_due) else None)
 
     def _on_timer(self, due: int) -> None:
         self._sched_due = None
@@ -2404,20 +2397,23 @@ class SiddhiAppRuntime:
             self.compile_service.warmup(buckets=buckets)
 
     # -- tracing / profiling (siddhi_tpu/obs/, docs/observability.md) -----
-    def trace_start(self) -> None:
+    def trace_start(self, capacity: Optional[int] = None) -> None:
         """Start recording chunk spans (ingest -> junction -> step ->
-        sink) into the tracer ring buffer."""
-        self.tracer.start()
+        wait/d2h/decode -> sink) into the tracer ring buffer, which
+        keeps the newest ``capacity`` spans (default 8,192)."""
+        self.tracer.start(capacity=capacity)
 
-    def trace_stop(self) -> None:
-        self.tracer.stop()
+    def trace_stop(self) -> int:
+        """Stop recording; returns the spans the ring evicted."""
+        return self.tracer.stop()
 
     def trace_export(self, path: str) -> str:
         """Write buffered chunk spans as Chrome ``trace_event`` JSON
         (chrome://tracing / Perfetto loadable), timestamp-ordered and —
         when the cost profiler has samples — annotated with measured
         per-step device time (``cost_ms_per_event`` etc. in span args);
-        returns ``path``."""
+        the ring's capacity and evicted-span count go in ``otherData``.
+        Returns ``path``."""
         return self.tracer.export(
             path, annotations=self.cost.trace_annotations())
 
@@ -2477,7 +2473,9 @@ class SiddhiAppRuntime:
 
             with rt.profile('/tmp/prof'):
                 handler.send_arrays(ts, cols)
-        """
+
+        Spans recorded meanwhile (``trace_start()``) land in the
+        trace's host plane, on the device ops' clock."""
         from ..obs.profiler import profile
         return profile(path)
 

@@ -25,7 +25,7 @@ import logging
 import threading
 from typing import Any, Callable, Optional, Sequence
 
-from ..obs.tracing import maybe_span
+from ..obs.tracing import maybe_chunk, maybe_span, tracer_of
 
 log = logging.getLogger("siddhi_tpu.stream")
 
@@ -123,7 +123,9 @@ class StreamJunction:
         # publishes are pre-split to <= batch.size.max at enqueue, so this
         # only ever coalesces whole items (order preserved via `carry`;
         # the _STOP sentinel also rides the carry slot so it is never
-        # lost when dequeued mid-coalesce)
+        # lost when dequeued mid-coalesce). An item is (chunk, events):
+        # the tracer's chunk number of the publish that queued it, which
+        # the dispatch's spans carry (a coalesced batch: its first item's)
         import queue as _q
         _, batch_max = self.async_conf
         carry = None
@@ -135,17 +137,18 @@ class StreamJunction:
                     self._pending -= 1
                     self._drained.notify_all()
                 return
-            batch = list(item)
+            chunk, events = item
+            batch = list(events)
             n_items = 1
             while len(batch) < batch_max:
                 try:
                     nxt = self._queue.get_nowait()
                 except _q.Empty:
                     break
-                if nxt is _STOP or len(batch) + len(nxt) > batch_max:
+                if nxt is _STOP or len(batch) + len(nxt[1]) > batch_max:
                     carry = nxt
                     break
-                batch.extend(nxt)
+                batch.extend(nxt[1])
                 n_items += 1
             _IN_DISPATCH.active = True
             # @Async streams open the sampled SLO span at DISPATCH time
@@ -155,7 +158,7 @@ class StreamJunction:
             tok = slo.ingest_begin(self.stream_id) if slo is not None \
                 else None
             try:
-                with self._app.barrier:
+                with self._app.barrier, maybe_chunk(self._app, chunk):
                     self._app.on_ingest(self.stream_id, batch)
                     self._publish_sync(batch)
             finally:
@@ -268,6 +271,8 @@ class StreamJunction:
             # Disruptor deadlocks outright in the same cycle).
             import queue as _q
             _, batch_max = self.async_conf
+            tracer = tracer_of(self._app)
+            chunk = tracer.current_chunk() if tracer is not None else None
             slices = [events[i:i + batch_max]
                       for i in range(0, len(events), batch_max)]
             for s in slices:
@@ -275,7 +280,7 @@ class StreamJunction:
                     try:
                         with self._drained:
                             self._pending += 1
-                        queue.put_nowait(s)
+                        queue.put_nowait((chunk, s))
                     except _q.Full:
                         with self._drained:
                             self._pending -= 1
@@ -288,7 +293,7 @@ class StreamJunction:
                 else:
                     with self._drained:
                         self._pending += 1
-                    queue.put(s)
+                    queue.put((chunk, s))
             return
         self._publish_sync(events)
 
@@ -308,8 +313,9 @@ class StreamJunction:
         decoded = None
 
         def decode():
-            from .event import EXPIRED, rows_from_batch
-            rows = rows_from_batch(self.schema.types, batch)
+            from .event import EXPIRED, OutputFetch
+            rows = OutputFetch(tracer_of(self.app), self.stream_id,
+                               self.schema.types, batch).rows()
             return [Event(ts, vals, is_expired=(kind == EXPIRED))
                     for ts, kind, vals in rows]
 
@@ -379,13 +385,15 @@ class InputHandler:
             # bounded-lateness reorder buffer (resilience/ordering.py):
             # events are buffered, watermark-sorted and released through
             # _dispatch_rows; late events resolve per the stream policy
-            with maybe_span(self.app, "ingest", self.stream_id,
-                            events=len(events), buffered=1), \
+            with maybe_chunk(self.app), \
+                    maybe_span(self.app, "ingest", self.stream_id,
+                               events=len(events), buffered=1), \
                     self.app.barrier:
                 buf.ingest_rows(events)
             return
-        with maybe_span(self.app, "ingest", self.stream_id,
-                        events=len(events)):
+        with maybe_chunk(self.app), \
+                maybe_span(self.app, "ingest", self.stream_id,
+                           events=len(events)):
             if self.junction._queue is not None:
                 # @Async: hand off to the junction's worker, which
                 # advances the clock when the batch is actually
@@ -400,27 +408,30 @@ class InputHandler:
         junction is @Async): advance the clock, publish, fire timers
         armed during processing. The reorder-buffer flush releases
         through here too, so buffered and direct ingest share one
-        dispatch contract."""
-        if self.junction._queue is not None:
-            self.junction.publish(events)
-            return
-        # sampled ingest->emit span (obs/slo.py): queries that decode
-        # host rows during this dispatch attribute against its start
-        slo = self.app.slo
-        tok = slo.ingest_begin(self.stream_id) if slo is not None \
-            else None
-        try:
-            self.app.on_ingest(self.stream_id, events)
-            self.junction.publish(events)
-            # timers armed DURING processing (e.g. hop boundaries the
-            # chunk's own event-time jump crossed) fire now, not at the
-            # next external tick
-            if self.app._playback and \
-                    self.app._playback_time is not None:
-                self.app.scheduler.advance_to(self.app._playback_time)
-        finally:
-            if tok is not None:
-                slo.ingest_end(tok)
+        dispatch contract. The tracer's chunk number is assigned here
+        unless the caller's thread already carries one."""
+        with maybe_chunk(self.app):
+            if self.junction._queue is not None:
+                self.junction.publish(events)
+                return
+            # sampled ingest->emit span (obs/slo.py): queries that decode
+            # host rows during this dispatch attribute against its start
+            slo = self.app.slo
+            tok = slo.ingest_begin(self.stream_id) if slo is not None \
+                else None
+            try:
+                self.app.on_ingest(self.stream_id, events)
+                self.junction.publish(events)
+                # timers armed DURING processing (e.g. hop boundaries
+                # the chunk's own event-time jump crossed) fire now, not
+                # at the next external tick
+                if self.app._playback and \
+                        self.app._playback_time is not None:
+                    self.app.scheduler.advance_to(
+                        self.app._playback_time)
+            finally:
+                if tok is not None:
+                    slo.ingest_end(tok)
 
     def send_arrays(self, ts, cols) -> None:
         """Columnar ingest: numpy timestamp + data column arrays
@@ -447,8 +458,9 @@ class InputHandler:
                 # chunks through _dispatch_arrays (same bucketed
                 # capacities, zero new jits)
                 self.junction.mark_ingest(n)
-                with maybe_span(self.app, "ingest", self.stream_id,
-                                rows=n, buffered=1), self.app.barrier:
+                with maybe_chunk(self.app), \
+                        maybe_span(self.app, "ingest", self.stream_id,
+                                   rows=n, buffered=1), self.app.barrier:
                     buf.ingest_columns(ts, cols)
                 return
             self._dispatch_arrays(ts, cols)
@@ -514,7 +526,9 @@ class InputHandler:
                         chunk=None) -> None:
         """Dispatch ONE bucketed chunk (serial and pipelined branches
         share this body; the pipelined branch passes a pre-encoded
-        ``chunk``)."""
+        ``chunk``). The tracer's chunk number is assigned here unless
+        the caller's thread already carries one (a reorder-buffer
+        release inside the send that buffered it)."""
         from .event import batch_from_columns
         from .ingest import PackedChunk
         from .runtime import bucket_capacity
@@ -526,8 +540,9 @@ class InputHandler:
         tok = slo.ingest_begin(self.stream_id) if slo is not None \
             else None
         try:
-            with maybe_span(self.app, "ingest", self.stream_id,
-                            rows=len(t)), self.app.barrier:
+            with maybe_chunk(self.app), \
+                    maybe_span(self.app, "ingest", self.stream_id,
+                               rows=len(t)), self.app.barrier:
                 # columnar fast path: fire only dues STRICTLY BEFORE
                 # the chunk's span now — in-span window expiry happens
                 # inside the chunk's own step at exact per-row points,
@@ -610,15 +625,7 @@ class InputHandler:
             t, c = slices[i]
             self._dispatch_chunk(t, c, True, mark, chunk=chunk)
 
-        st = self._pipeline.stats
-        before = (st["wall_s"], st["overlap_s"])
-        with maybe_span(app, "ingest_pipeline", self.stream_id,
-                        chunks=len(slices), rows=n) as sp:
-            self._pipeline.run(len(slices), encode, dispatch)
-            # overlap attribution on the span itself: how much of this
-            # send's encode ran concurrently with H2D/compute
-            sp.set(wall_s=round(st["wall_s"] - before[0], 6),
-                   overlap_s=round(st["overlap_s"] - before[1], 6))
+        self._pipeline.run(len(slices), encode, dispatch)
 
     def _dispatch_device_batch(self, batch, first_ts: int,
                                last_ts: int) -> None:
@@ -631,8 +638,10 @@ class InputHandler:
         tok = slo.ingest_begin(self.stream_id) if slo is not None \
             else None
         try:
-            with maybe_span(self.app, "ingest", self.stream_id,
-                            rows=int(batch.capacity)), self.app.barrier:
+            with maybe_chunk(self.app), \
+                    maybe_span(self.app, "ingest", self.stream_id,
+                               rows=int(batch.capacity)), \
+                    self.app.barrier:
                 self.app.on_ingest_span(int(first_ts), int(last_ts))
                 self.junction.publish_batch(batch, int(last_ts))
                 if self.app._playback:

@@ -19,9 +19,10 @@ an XLA program per dispatch, never finer:
 
 - ``query/<q>``          one plain query step
 - ``chain/<q1+q2+...>``  one fused insert-into segment (the segment IS
-                         one XLA program; per-member split needs a
-                         device profile with SIDDHI_TPU_PROFILE_SCOPES=1
-                         — members are listed in the report instead)
+                         one XLA program; the per-member split is in a
+                         device profile, whose op names carry each
+                         member's ``<q>/<OpClass>`` scope — members are
+                         listed in the report instead)
 - ``join/<q>.left|right[grid|probe]``  one join side step; the suffix
                          names the kernel that ran (the [B,W] broadcast
                          grid or the banded searchsorted probe — the

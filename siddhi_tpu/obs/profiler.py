@@ -1,34 +1,25 @@
 """Profiler hooks: ``runtime.profile(path)`` around
-``jax.profiler.start_trace/stop_trace``, and opt-in ``jax.named_scope``
+``jax.profiler.start_trace/stop_trace``, and the ``jax.named_scope``
 labels inside step traces.
 
-Named scopes are STRICTLY opt-in behind ``SIDDHI_TPU_PROFILE_SCOPES=1``:
-scope metadata changes the lowered HLO, which changes the persistent
-compile-cache key (docs/compile_cache.md cache-key rules) — flipping
-the default would invalidate every existing ``.jax_cache`` entry. The
-env var is read at trace time (traces are rare; dispatches are not), so
-enabling it recompiles the steps exactly once per process.
+Every operator of a step body runs under ``op_scope("<q>/<OpClass>")``,
+so each compiled op's ``op_name`` metadata names its query and operator
+and a device profile attributes time per operator. The scopes are
+debug info only: JAX strips debug info from the module before hashing
+it into the persistent compile-cache key (``jax/_src/cache_key.py``,
+``compilation_cache_include_metadata_in_key`` off by default), so a
+scoped step hits the cache entry of the same step lowered without them.
 """
 from __future__ import annotations
 
 import contextlib
-import os
 
-SCOPES_ENV = "SIDDHI_TPU_PROFILE_SCOPES"
-
-
-def scopes_enabled() -> bool:
-    return os.environ.get(SCOPES_ENV, "") == "1"
+import jax
 
 
 def op_scope(name: str):
-    """``jax.named_scope(name)`` when profiling scopes are enabled, else
-    a nullcontext — used around each operator inside step traces so
-    device profiles attribute time to operators instead of one opaque
-    fused computation."""
-    if not scopes_enabled():
-        return contextlib.nullcontext()
-    import jax
+    """``jax.named_scope(name)`` around one operator inside a step
+    trace."""
     return jax.named_scope(name)
 
 
@@ -39,8 +30,10 @@ def profile(path: str):
 
         with runtime.profile('/tmp/prof'):
             handler.send_arrays(ts, cols)
-    """
-    import jax
+
+    While the app's tracer records (``runtime.trace_start()``), its
+    chunk spans appear in the trace's host plane beside the device
+    ops."""
     jax.profiler.start_trace(path)
     try:
         yield path

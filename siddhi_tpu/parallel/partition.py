@@ -59,10 +59,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.event import (CURRENT, EventBatch, StreamSchema, TIMER,
-                          rows_from_batch)
+from ..core.event import (CURRENT, EventBatch, OutputFetch, StreamSchema,
+                          TIMER)
 from ..core.stream import Event, Receiver
-from ..obs.tracing import maybe_span
+from ..obs.tracing import maybe_span, tracer_of
 from ..ops.expr import CompiledExpr, env_from_batch
 from ..ops.keyed import hash_columns, lookup_or_insert
 from ..ops.windows import POS_INF, WindowOp
@@ -522,9 +522,12 @@ class PartitionBlockRuntime:
         port = self.ports[qname]
         for cb in port.batch_callbacks:
             cb(out)
+        # the output fetch's spans are named by the partition, like the
+        # enclosing partition/<name> span
+        fetch = OutputFetch(tracer_of(self.app), self.name,
+                            port.out_schema.types, out)
         if port.rate_limiter is not None:
-            out_host = jax.device_get(out)
-            rows = rows_from_batch(port.out_schema.types, out_host)
+            rows = fetch.rows()
             if rows:
                 port.rate_limiter.process(timestamp, rows)
             return
@@ -532,8 +535,7 @@ class PartitionBlockRuntime:
                         if not h.handle_device_batch(out, timestamp)]
         if not (row_handlers or port.callback_handler.callbacks):
             return
-        out_host = jax.device_get(out)
-        rows = rows_from_batch(port.out_schema.types, out_host)
+        rows = fetch.rows()
         if not rows:
             return
         for h in row_handlers:

@@ -530,6 +530,7 @@ class FanoutGroup:
         self.app = app
         self.junction = junction
         self.units = list(units)
+        self.members = tuple(u.name for u in self.units)  # span args
         self.name = junction.stream_id      # stable cost-center name
         self.display = "|".join(u.name for u in self.units)
         self.queries = [q for u in self.units
@@ -574,7 +575,7 @@ class FanoutGroup:
             return u._chain
         k = self._classes[self._cse_class[ui]]["k"] \
             if self._cse_class[ui] is not None else 0
-        body = _chain_body(u.operators[k:], u._has_timers)
+        body = _chain_body(u.operators[k:], u._has_timers, u.name)
         if k == 0:
             return body
 
@@ -603,7 +604,7 @@ class FanoutGroup:
                     else shared[cls["parent"]]
                 rep = units[cls["rep"]]
                 for op in rep.operators[cls["pk"]:cls["k"]]:
-                    with op_scope(type(op).__name__):
+                    with op_scope(f"{rep.name}/{type(op).__name__}"):
                         _, cur = op.step((), cur, now)
                 shared[ci] = cur
             new_states, new_emitted, outs, dues = [], [], [], []
@@ -709,7 +710,7 @@ class FanoutGroup:
         probe = cost.probe("fanout", self.name, cap=chunk.capacity) \
             if cost.enabled else None
         with self.app.tracer.span("fanout", self.name, rows=chunk.n,
-                                  members=[u.name for u in self.units]):
+                                  members=self.members):
             lats = [lat for h in self._heads
                     if (lat := h._stats_mark(chunk.n)) is not None]
             for q in self.queries:
@@ -738,7 +739,7 @@ class FanoutGroup:
         cost = self.app.cost
         probe = cost.probe("fanout", self.name) if cost.enabled else None
         with self.app.tracer.span("fanout", self.name,
-                                  members=[u.name for u in self.units]):
+                                  members=self.members):
             if now is None:
                 now = self.app.current_time()
             lats = [lat for h in self._heads

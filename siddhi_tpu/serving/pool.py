@@ -1302,7 +1302,7 @@ class TenantPool:
         q = self.proto.queries[qname]
         sel_ops = q.operators
         if kind == "chain":
-            chain = _chain_body(q.operators, q._has_timers)
+            chain = _chain_body(q.operators, q._has_timers, qname)
 
             def body(states, emitted, batch, now):
                 states, _t, emitted, out, _due = chain(

@@ -20,7 +20,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from siddhi_tpu import SiddhiManager
+from siddhi_tpu import Event, SiddhiManager, StreamCallback
 from siddhi_tpu.core.types import GLOBAL_STRINGS
 from siddhi_tpu.obs.metrics import (Counter, Gauge, Histogram,
                                     MetricsRegistry, prom_name)
@@ -720,14 +720,268 @@ class TestTracing:
         rt.shutdown()
         assert prof_dir.exists() and any(prof_dir.rglob("*"))
 
-    def test_named_scopes_gated_off_by_default(self, monkeypatch):
+    def test_named_scopes_name_query_and_operator_without_moving_the_cache_key(
+            self, monkeypatch):
+        """Every operator of a step runs under ``<q>/<OpClass>``: the
+        compiled HLO's op_name metadata carries it, and the persistent
+        compile-cache key equals that of the same step lowered without
+        scopes (JAX strips debug info before hashing)."""
         import contextlib
-        from siddhi_tpu.obs.profiler import op_scope
-        monkeypatch.delenv("SIDDHI_TPU_PROFILE_SCOPES", raising=False)
-        assert isinstance(op_scope("FilterOp"), contextlib.nullcontext)
-        monkeypatch.setenv("SIDDHI_TPU_PROFILE_SCOPES", "1")
-        scope = op_scope("FilterOp")
-        assert not isinstance(scope, contextlib.nullcontext)
+        import re
+
+        import jax
+        from jax._src import cache_key, compiler
+
+        from siddhi_tpu.core import runtime as runtime_mod
+
+        rt = _playback_app("""
+            define stream S (v int);
+            @info(name = 'qs') from S[v > 3] select v * 2 as w
+            insert into Out;
+        """)
+        q = rt.queries["qs"]
+        batch = runtime_mod._timer_batch(q.in_schema, TS0)
+        args = (q.states, {}, q._emitted_dev, batch, np.int64(TS0))
+        scoped = q._make_step().lower(*args)
+        monkeypatch.setattr(runtime_mod, "op_scope",
+                            lambda name: contextlib.nullcontext())
+        plain = q._make_step().lower(*args)
+        rt.shutdown()
+
+        assert "qs/FilterOp" not in plain.as_text(debug_info=True)
+        # compiled afresh: a persistent-cache hit would return whichever
+        # lowering wrote the entry, since both share its key
+        from jax._src import config as jax_config
+        with jax_config.enable_compilation_cache(False):
+            hlo = scoped.compile().as_text()
+        assert re.search(r'op_name="[^"]*qs/FilterOp', hlo)
+        assert re.search(r'op_name="[^"]*qs/ProjectOp', hlo)
+
+        dev = jax.devices()[0]
+        devices = np.array([dev])
+        opts = compiler.get_compile_options(num_replicas=1,
+                                            num_partitions=1)
+
+        def key(lowered):
+            return cache_key.get(lowered.compiler_ir("stablehlo"), devices,
+                                 opts, dev.client)
+
+        assert key(scoped) == key(plain)
+
+    def test_ring_capacity_counts_evicted_spans(self, tmp_path):
+        rt = _playback_app(CHAIN_APP)
+        rt.trace_start(capacity=4)
+        for i in range(6):
+            _send_ramp(rt, "S", 16, base=TS0 + 16 * i)
+        events = rt.tracer.events()
+        dropped = rt.trace_stop()
+        path = rt.trace_export(str(tmp_path / "t.json"))
+        rt.shutdown()
+        assert len(events) == 4
+        # each send records ingest/S and chain/q1+q2 (+ the output's
+        # spans where rows were decoded): 6 sends leave more than 4
+        assert dropped >= 2 * 6 - 4
+        meta = json.load(open(path))["otherData"]
+        assert meta == {"capacity": 4, "dropped": dropped}
+        # a fresh start clears the count and keeps the capacity
+        rt2 = _playback_app(CHAIN_APP)
+        rt2.trace_start()
+        assert rt2.tracer.capacity == 8192 and rt2.tracer.dropped == 0
+        rt2.shutdown()
+
+    def test_ingest_pipeline_span_removed(self, monkeypatch):
+        """A pipelined send (more rows than the split cap) records one
+        ingest/<s> span per chunk and no span of its own."""
+        monkeypatch.setenv("SIDDHI_TPU_INGEST_PIPELINE_CHUNK", "128")
+        rt = _playback_app(CHAIN_APP)
+        rt.trace_start()
+        _send_ramp(rt, "S", 512)
+        names = [e[0] for e in rt.tracer.events()]
+        rt.shutdown()
+        assert names.count("ingest/S") == 4
+        assert not any(n.startswith("ingest_pipeline") for n in names)
+
+    def test_chunk_id_crosses_async_junction(self):
+        rt = SiddhiManager().create_siddhi_app_runtime("""
+            @app:playback
+            @Async(buffer.size='64', batch.size.max='8')
+            define stream S (v int);
+            @info(name = 'q') from S[v > 0] select v insert into O;
+        """)
+        got = []
+        rt.add_callback("O", StreamCallback(lambda evs: got.extend(evs)))
+        rt.start()
+        rt.trace_start()
+        h = rt.get_input_handler("S")
+        for k in range(3):
+            h.send([Event(TS0 + 8 * k + i, (i + 1,)) for i in range(8)])
+            rt.junctions["S"].flush_async()
+        events = rt.tracer.events()
+        rt.shutdown()
+        assert len(got) == 24
+        ingest = [e for e in events if e[0] == "ingest/S"]
+        steps = [e for e in events if e[0] == "step/q"]
+        assert [e[5]["chunk"] for e in ingest] == [0, 1, 2]
+        # the step runs on the junction's worker thread, under the
+        # chunk number of the send that queued its batch
+        assert {e[4] for e in steps}.isdisjoint({e[4] for e in ingest})
+        assert [e[5]["chunk"] for e in steps] == [0, 1, 2]
+        for e in events:
+            if e[0] in ("wait/q", "d2h/q", "decode/q"):
+                assert e[5]["chunk"] in (0, 1, 2)
+
+    def test_profiler_host_plane_holds_every_span_on_one_offset(
+            self, tmp_path):
+        """Inside a profiler session every ChunkTracer span is also a
+        host-plane event of the same name; the two clocks differ by one
+        offset."""
+        import collections
+        import glob
+
+        from jax.profiler import ProfileData
+        rt = SiddhiManager().create_siddhi_app_runtime(CHAIN_APP)
+        rt.add_callback("Out", StreamCallback(lambda evs: None))
+        rt.start()
+        prof = tmp_path / "prof"
+        try:
+            with rt.profile(str(prof)):
+                rt.trace_start()
+                for i in range(4):
+                    _send_ramp(rt, "S", 64, base=TS0 + 64 * i)
+                rt.trace_stop()
+        except Exception as e:  # noqa: BLE001 — backend-dependent
+            rt.shutdown()
+            pytest.skip(f"jax profiler unavailable: {e}")
+        spans = rt.tracer.events()
+        rt.shutdown()
+        files = glob.glob(str(prof / "**" / "*.xplane.pb"), recursive=True)
+        assert len(files) == 1
+        host = collections.defaultdict(list)
+        for plane in ProfileData.from_file(files[0]).planes:
+            if plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name].append(int(ev.start_ns))
+        mine = collections.defaultdict(list)
+        for name, _, ts_us, _, _, _ in spans:
+            mine[name].append(ts_us)
+        assert {"ingest/S", "chain/q1+q2", "wait/q2", "d2h/q2",
+                "decode/q2"} <= set(mine)
+        offsets = []
+        for name, ts in mine.items():
+            xs = sorted(host[name])
+            assert len(xs) == len(ts), name
+            offsets += [x - t * 1000 for x, t in zip(xs, sorted(ts))]
+        assert max(offsets) - min(offsets) < 100_000
+
+
+JOIN_APP = """
+    @app:playback
+    define stream S (k string, v long);
+    define stream T (k string, w long);
+    define table Tab (k string, w long);
+    @info(name = 'load') from T select k, w insert into Tab;
+    @info(name = 'j')
+    from S join Tab on S.k == Tab.k
+    select S.k as k, S.v as v, Tab.w as w insert into Out;
+"""
+
+# (app, enclosing span, name of the output-fetch spans)
+DISPATCH_PATHS = {
+    "single": ("""
+        @app:playback
+        define stream S (k string, v long);
+        @info(name = 'q') from S[v > 2] select k, v insert into Out;
+    """, "step/q", "q"),
+    "fused_chain": ("""
+        @app:playback
+        define stream S (k string, v long);
+        @info(name = 'q1') from S[v > 2] select k, v insert into M;
+        @info(name = 'q2') from M[v < 60] select k, v insert into Out;
+    """, "chain/q1+q2", "q2"),
+    "join_side": (JOIN_APP, "step/j", "j"),
+    "pattern": ("""
+        @app:playback
+        define stream S (k string, v long);
+        @info(name = 'p')
+        from every e1=S[v > 0] -> e2=S[k == e1.k and v > e1.v]
+        select e1.k as k, e2.v as v insert into Out;
+    """, "step/p", "p"),
+    "partition": ("""
+        @app:playback
+        define stream S (k string, v long);
+        partition with (k of S) begin
+          @info(name = 'pq') from S select k, sum(v) as v insert into Out;
+        end;
+    """, "partition/", None),
+}
+
+
+def _run_path(path: str, trace: bool):
+    ql = DISPATCH_PATHS[path][0]
+    rt = SiddhiManager().create_siddhi_app_runtime(ql)
+    got = []
+    rt.add_callback("Out", StreamCallback(
+        lambda evs: got.extend((e.timestamp, e.data) for e in evs)))
+    rt.start()
+    codes = np.array([GLOBAL_STRINGS.encode(f"K{i % 4}") for i in
+                      range(64)], np.int32)
+    if path == "join_side":
+        rt.get_input_handler("T").send_arrays(
+            np.full(4, TS0 - 1, np.int64),
+            [codes[:4], np.arange(4, dtype=np.int64)])
+    if trace:
+        rt.trace_start()
+    h = rt.get_input_handler("S")
+    for i in range(2):
+        h.send_arrays(TS0 + 64 * i + np.arange(64, dtype=np.int64),
+                      [codes, np.arange(64, dtype=np.int64)])
+    events = rt.tracer.events()
+    parts = list(rt.partitions)
+    rt.shutdown()
+    return got, events, parts
+
+
+@pytest.mark.parametrize("path", sorted(DISPATCH_PATHS))
+def test_output_fetch_spans_nest_in_the_dispatch_span(path):
+    """Where a step's output becomes host rows, wait/<q>, d2h/<q> and
+    decode/<q> nest, in that order, inside the dispatch's own span, and
+    every span carries its chunk's number; d2h carries the rows copied
+    (capacity) and bytes, decode the valid rows out."""
+    got, events, parts = _run_path(path, trace=True)
+    _, outer, q = DISPATCH_PATHS[path]
+    if q is None:       # a partition's spans carry the partition's name
+        q = parts[0]
+        outer = f"partition/{q}"
+    assert got
+    outers = [e for e in events if e[0] == outer]
+    assert outers
+    fetch = {k: [e for e in events if e[0] == f"{k}/{q}"]
+             for k in ("wait", "d2h", "decode")}
+    assert all(fetch.values()), fetch
+    for e in events:
+        assert e[5]["chunk"] in (0, 1), e
+    for kind, spans in fetch.items():
+        for name, _, ts, dur, tid, args in spans:
+            host = [o for o in outers if o[4] == tid and o[2] <= ts and
+                    ts + dur <= o[2] + o[3] and
+                    o[5]["chunk"] == args["chunk"]]
+            assert host, (name, outers)
+    for (_, _, wts, wdur, _, _), (_, _, dts, ddur, _, dargs), \
+            (_, _, cts, _, _, cargs) in zip(*fetch.values()):
+        assert wts + wdur <= dts and dts + ddur <= cts
+        assert dargs["capacity"] >= cargs["rows"] > 0
+        assert dargs["bytes"] > 0
+    assert sum(a["rows"] for *_, a in fetch["decode"]) == len(got)
+
+
+@pytest.mark.parametrize("path", sorted(DISPATCH_PATHS))
+def test_tracing_off_records_nothing_and_changes_no_row(path):
+    got_on, events_on, _ = _run_path(path, trace=True)
+    got_off, events_off, _ = _run_path(path, trace=False)
+    assert events_on and events_off == []
+    assert got_off == got_on
 
 
 # ---------------------------------------------------------------------------
