@@ -263,7 +263,7 @@ class AggregationRuntime(Receiver):
                 hk = hash_columns(
                     [bs] + [c.values for c in gcols],
                     [jnp.zeros_like(active)] + [c.nulls for c in gcols])
-                slots, keys, used, ovf = lookup_or_insert(
+                slots, keys, used, ovf, _ = lookup_or_insert(
                     st["keys"], st["used"], hk, active)
                 ok = active & (slots >= 0)
                 tgt = jnp.where(ok, slots, jnp.int32(K))

@@ -588,10 +588,13 @@ class QueryRuntime(Receiver):
                                   due=due if self._has_timers else None)
 
     def stats(self) -> dict:
-        """Runtime counters (device-synced on read)."""
+        """Runtime counters (device-synced on read). ``probe_rounds`` /
+        ``probe_steps`` is the mean number of group-key probe rounds
+        (ops/keyed.py lookup_or_insert) per step that probed."""
         with self._lock:  # vs restore_state rebinding the counter
             emitted = int(jax.device_get(self._emitted_dev))
-        return {"emitted": emitted, "overflow": self.overflow_total()}
+        return {"emitted": emitted, "overflow": self.overflow_total(),
+                **self._state_sums(("probe_rounds", "probe_steps"))}
 
     # -- snapshot (SnapshotService state walk -> one device_get) ----------
     def snapshot_state(self) -> dict:
@@ -604,7 +607,7 @@ class QueryRuntime(Receiver):
 
     def restore_state(self, snap: dict) -> None:
         with self._lock:
-            self.states = _fresh_device(snap["states"])
+            self.states = self._restored_states(snap["states"])
             self._emitted_dev = jnp.array(snap["emitted"], copy=True)
             self._sched_due = None
             if self.rate_limiter is not None and "rate" in snap:
@@ -624,18 +627,30 @@ class QueryRuntime(Receiver):
             # one transfer for all window dues, not one sync per window
             self._schedule(min(int(d) for d in jax.device_get(dues)))
 
-    def overflow_total(self) -> int:
-        """Sum of overflow counters across operator states (windows etc.;
-        the 'counted, never silent' contract). Walks nested state dicts
-        — aggregator tables carry their own counters."""
-        total = 0
+    def _restored_states(self, snapped) -> tuple:
+        """Fresh device operator states from a snapshot. A key of an
+        operator's state that the snapshot predates (a counter added
+        since it was taken) restores at the operator's initial value."""
+        states = []
+        for op, st in zip(self.operators, snapped):
+            if isinstance(st, dict):
+                init = op.init_state()
+                if init.keys() - st.keys():
+                    st = {**init, **st}
+            states.append(st)
+        return _fresh_device(tuple(states))
+
+    def _state_sums(self, names) -> dict:
+        """Sum of each named scalar counter across the operator states.
+        Walks nested state dicts — aggregator tables carry their own
+        counters — and reads the counters found in one transfer."""
+        found = {n: [] for n in names}
 
         def walk(st):
-            nonlocal total
             if isinstance(st, dict):
                 for k, v in st.items():
-                    if k == "overflow":
-                        total += int(v)
+                    if k in found:
+                        found[k].append(v)
                     else:
                         walk(v)
             elif isinstance(st, (tuple, list)):
@@ -643,9 +658,14 @@ class QueryRuntime(Receiver):
                     walk(v)
 
         with self._lock:  # vs restore_state rebinding mid-walk
-            host = jax.device_get(self.states)
-        walk(host)
-        return total
+            walk(self.states)
+            host = jax.device_get(found)
+        return {n: sum(int(v) for v in vs) for n, vs in host.items()}
+
+    def overflow_total(self) -> int:
+        """Sum of overflow counters across operator states (windows etc.;
+        the 'counted, never silent' contract)."""
+        return self._state_sums(("overflow",))["overflow"]
 
     # -- runtime ---------------------------------------------------------
     @staticmethod
@@ -1179,7 +1199,7 @@ class PatternQueryRuntime(QueryRuntime):
 
     def restore_state(self, snap: dict) -> None:
         with self._lock:
-            self.states = _fresh_device(snap["states"])
+            self.states = self._restored_states(snap["states"])
             self._emitted_dev = jnp.array(snap["emitted"], copy=True)
             self.nfa_state = _fresh_device(snap["nfa"])
             self._sched_due = None
@@ -1442,7 +1462,7 @@ class JoinQueryRuntime(QueryRuntime):
 
     def restore_state(self, snap: dict) -> None:
         with self._lock:
-            self.states = _fresh_device(snap["states"])
+            self.states = self._restored_states(snap["states"])
             self._emitted_dev = jnp.array(snap["emitted"], copy=True)
             self.side_states = _fresh_device(snap["sides"])
             self._overflow_dev = jnp.array(snap["join_overflow"],
@@ -2136,6 +2156,8 @@ class SiddhiAppRuntime:
             base = f"{p}.query.{n}"
             for key, metric in (("emitted", "emitted"),
                                 ("overflow", "overflow"),
+                                ("probe_rounds", "probe_rounds"),
+                                ("probe_steps", "probe_steps"),
                                 ("throughput_eps", "throughput"),
                                 ("state_bytes", "state.bytes")):
                 v = entry.get(key)

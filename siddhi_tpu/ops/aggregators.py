@@ -314,7 +314,7 @@ class DistinctCountAgg(AggSpec):
         ph = hash_columns(
             [slots.astype(jnp.int64), arg.values],
             [jnp.zeros((B,), jnp.bool_), arg.nulls])
-        pslots, pkeys, pused, ovf = lookup_or_insert(
+        pslots, pkeys, pused, ovf, _ = lookup_or_insert(
             tab["keys"], tab["used"], ph, agg_row)
         tracked = agg_row & (pslots >= 0)
         sgn = jnp.where(tracked & is_add, jnp.int64(1),
@@ -820,6 +820,9 @@ class AggregateOp(Operator):
                 if getattr(spec, "stateful", False) else ()
                 for spec in self.agg_specs),
             "overflow": jnp.int64(0),
+            # group-key probe loop: rounds run, and steps that probed
+            "probe_rounds": jnp.int64(0),
+            "probe_steps": jnp.int64(0),
         }
 
     def step(self, state, batch: EventBatch, now):
@@ -834,12 +837,16 @@ class AggregateOp(Operator):
 
         # --- group slots -------------------------------------------------
         overflow = state["overflow"]
+        probe_rounds = state["probe_rounds"]
+        probe_steps = state["probe_steps"]
         if self.group_by:
             key_cols = [ce.fn(env) for ce in self.key_exprs]
             hkeys = hash_columns([c.values for c in key_cols],
                                  [c.nulls for c in key_cols])
-            slots, new_keys, new_used, ov = lookup_or_insert(
+            slots, new_keys, new_used, ov, rounds = lookup_or_insert(
                 state["keys"], state["used"], hkeys, agg_row)
+            probe_rounds = probe_rounds + rounds.astype(jnp.int64)
+            probe_steps = probe_steps + (rounds > 0).astype(jnp.int64)
             # overflowed keys (slot table full) are parked on the trash slot
             # K: excluded from state, carry, and output — counted, not
             # silently mis-aggregated
@@ -997,7 +1004,9 @@ class AggregateOp(Operator):
 
         new_state = {"keys": new_keys, "used": new_used,
                      "carry": tuple(new_carries),
-                     "tables": tuple(new_tables), "overflow": overflow}
+                     "tables": tuple(new_tables), "overflow": overflow,
+                     "probe_rounds": probe_rounds,
+                     "probe_steps": probe_steps}
         return new_state, out
 
 

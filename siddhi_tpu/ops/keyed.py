@@ -6,8 +6,12 @@ maps (util/snapshot/state/PartitionStateHolder.java:36 — HashMap keyed by
 
 Keys here are 64-bit mixes of the group-by columns (dictionary codes for
 strings, bit patterns for floats). A key is assigned a stable slot in a
-fixed-capacity table; slot state lives in dense [K, ...] arrays so per-key
-aggregation is pure gather/scatter — no host round-trip per key.
+fixed-capacity table by linear probing; slot state lives in dense [K, ...]
+arrays so per-key aggregation is pure gather/scatter — no host round-trip
+per key. The probe loop runs on the device in rounds over the whole batch
+and stops once every row is placed: a table holding the batch's keys at
+their home slots costs one round; a row that finds neither its key nor a
+free slot within max_probes slots overflows after max_probes rounds.
 
 Collision note: 64-bit mixing makes key collisions vanishingly unlikely but
 not impossible; the reference's string keys cannot collide. Accepted
@@ -53,13 +57,16 @@ def lookup_or_insert(table_keys, used, keys, active, max_probes: int = 16):
     table_keys: [K] int64, used: [K] bool, keys: [B] int64,
     active: [B] bool (rows that need a slot).
     Returns (slots [B] int32 — NO_SLOT when overflowed, table_keys', used',
-    overflow_count).
+    overflow_count, rounds — int32 probe rounds run).
 
-    Probe rounds are data-independent: each round every still-pending row
-    (a) matches its key against the probed slot, (b) races to claim it when
-    free (winner = lowest row index, via scatter-min), (c) re-checks after
-    claims land (two rows inserting the SAME new key resolve on the re-check),
-    else advances to the next slot.
+    Each round every still-pending row (a) matches its key against the
+    probed slot, (b) races to claim it when free (winner = lowest row
+    index, via scatter-min), (c) re-checks after claims land (two rows
+    inserting the SAME new key resolve on the re-check), else advances to
+    the next slot. A row placed in round r sits r slots past its home
+    slot. The loop ends once every active row is placed, or after
+    max_probes rounds (the rows still pending then overflow): a round
+    with no pending row would change nothing.
     """
     K = table_keys.shape[0]
     B = keys.shape[0]
@@ -68,8 +75,12 @@ def lookup_or_insert(table_keys, used, keys, active, max_probes: int = 16):
     placed = ~active
     result = jnp.full((B,), NO_SLOT, dtype=jnp.int32)
 
-    def round_body(carry, _):
-        table_keys, used, slot, placed, result = carry
+    def pending_rows(carry):
+        i, _, _, _, placed, _ = carry
+        return (i < max_probes) & jnp.any(~placed)
+
+    def round_body(carry):
+        i, table_keys, used, slot, placed, result = carry
         pending = ~placed
         occ = used[slot]
         match = pending & occ & (table_keys[slot] == keys)
@@ -86,13 +97,13 @@ def lookup_or_insert(table_keys, used, keys, active, max_probes: int = 16):
         result = jnp.where(match, slot, result)
         placed = placed | match
         slot = jnp.where(placed, slot, (slot + 1) % K)
-        return (table_keys, used, slot, placed, result), None
+        return i + 1, table_keys, used, slot, placed, result
 
-    (table_keys, used, slot, placed, result), _ = jax.lax.scan(
-        round_body, (table_keys, used, slot, placed, result), None,
-        length=max_probes)
+    rounds, table_keys, used, slot, placed, result = jax.lax.while_loop(
+        pending_rows, round_body,
+        (jnp.int32(0), table_keys, used, slot, placed, result))
     overflow = jnp.sum((active & (result == NO_SLOT)).astype(jnp.int64))
-    return result, table_keys, used, overflow
+    return result, table_keys, used, overflow, rounds
 
 
 # ---------------------------------------------------------------------------

@@ -1178,7 +1178,7 @@ class SessionWindowOp(WindowOp):
                                  [batch.nulls[self.key_idx]])
         else:
             khash = jnp.zeros((B,), jnp.int64)
-        slots, keys, used, kovf = lookup_or_insert(
+        slots, keys, used, kovf, _ = lookup_or_insert(
             state["keys"], state["used"], khash, cur)
         routed = cur & (slots >= 0)
 

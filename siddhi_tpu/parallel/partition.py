@@ -346,7 +346,7 @@ class PartitionBlockRuntime:
             c = cexpr.fn(env)
             codes = hash_columns([c.values], [c.nulls])
             active = batch.valid & (batch.kind != TIMER)
-            slots, keys, used, ovf = lookup_or_insert(
+            slots, keys, used, ovf, _ = lookup_or_insert(
                 slot_tbl["keys"], slot_tbl["used"], codes, active)
             slot_tbl = {"keys": keys, "used": used,
                         "overflow": slot_tbl["overflow"] + ovf}

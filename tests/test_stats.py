@@ -111,3 +111,70 @@ def test_group_by_key_overflow_counted():
     h.send_arrays(ts, [codes, np.ones(n, np.int64)])
     assert q.overflow_total() > 0
     rt.shutdown()
+
+
+YSB_COUNTS = """
+    define stream Views (campaign_id int, event_time long);
+    @info(name = 'counts')
+    from Views#window.externalTimeBatch(event_time, 10 sec, 0)
+    select campaign_id, count() as views
+    group by campaign_id
+    insert into Out;
+"""
+# dictionary codes of 100 campaigns; in a 1,024-slot table they probe to
+# distances 0-2, as the benchmark's campaign codes do
+CAMPAIGN_CODES = np.arange(1200, 1300, dtype=np.int32)
+
+
+def _send_window_chunks(rt, first, n, rows=1024):
+    """n chunks of `rows` views, one 10 s window each: every chunk closes
+    the window of the chunk before it."""
+    h = rt.get_input_handler("Views")
+    rng = np.random.default_rng(first)
+    for c in range(first, first + n):
+        t = 1_000_000_000 + c * 10_000 + np.arange(rows, dtype=np.int64) * 9
+        h.send_arrays(t, [rng.choice(CAMPAIGN_CODES, rows), t])
+
+
+def test_group_key_probe_rounds_counted():
+    rt = _playback_app(YSB_COUNTS)
+    q = rt.queries["counts"]
+    _send_window_chunks(rt, 0, 2)   # the first window closes: keys insert
+    first = q.stats()
+    assert first["probe_steps"] == 1
+    _send_window_chunks(rt, 2, 4)
+    stats = q.stats()
+    assert stats["overflow"] == 0
+    assert stats["probe_steps"] == 5
+    # after the first probing step every key is in the table
+    per_step = ((stats["probe_rounds"] - first["probe_rounds"]) /
+                (stats["probe_steps"] - first["probe_steps"]))
+    assert 1 <= per_step <= 3
+    flat = rt.metrics.collect()
+    base = f"siddhi.{rt.name}.query.counts"
+    assert flat[f"{base}.probe_rounds"] == stats["probe_rounds"]
+    assert flat[f"{base}.probe_steps"] == stats["probe_steps"]
+    assert flat[f"{base}.overflow"] == 0
+    rt.shutdown()
+
+
+def test_snapshot_without_probe_counters_restores():
+    """A snapshot taken before the probe counters existed restores with
+    them at 0, and the query keeps its keys and counts on."""
+    rt = _playback_app(YSB_COUNTS)
+    got = []
+    rt.add_callback("Out", StreamCallback(fn=lambda evs: got.extend(evs)))
+    q = rt.queries["counts"]
+    _send_window_chunks(rt, 0, 2)
+    snap = q.snapshot_state()
+    snap["states"] = tuple(
+        {k: v for k, v in st.items()
+         if k not in ("probe_rounds", "probe_steps")}
+        if isinstance(st, dict) else st for st in snap["states"])
+    q.restore_state(snap)
+    assert q.stats()["probe_steps"] == 0
+    _send_window_chunks(rt, 2, 1)
+    stats = q.stats()
+    assert stats["probe_steps"] == 1 and stats["overflow"] == 0
+    assert len(got) == 2 * len(set(CAMPAIGN_CODES.tolist()))
+    rt.shutdown()
