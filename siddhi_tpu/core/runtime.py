@@ -46,6 +46,7 @@ from ..ops.expr import (CompileError, SingleStreamScope,
 from ..ops.join import (JoinCombinedScope, JoinCross, JoinSideScope,
                         combined_schema)
 from ..ops.nfa import MatchScope, NfaCompiler, NfaEngine
+from ..ops.nfa_parallel import COUNTERS as NFA_COUNTERS
 from ..ops.nfa_parallel import ParallelNfaEngine, parallel_supported
 from ..ops.operators import FilterOp, Operator
 from ..ops.selector import (ProjectOp, output_attribute_name,
@@ -1191,6 +1192,19 @@ class PatternQueryRuntime(QueryRuntime):
         total = super().overflow_total()
         return total + int(jax.device_get(self.nfa_state["overflow"]))
 
+    def stats(self) -> dict:
+        """The base counters plus the parallel NFA engine's, read from its
+        table in one transfer (ops/nfa_parallel.py ``COUNTERS``): steps,
+        events, live rows at step start and end (summed over steps), the
+        live peak, rows spawned, completed and pruned, and the row x event
+        candidates the rounds evaluated."""
+        out = super().stats()
+        with self._lock:
+            nfa = self.nfa_state
+            found = {k: nfa[k] for k in NFA_COUNTERS if k in nfa}
+        out.update({k: int(v) for k, v in jax.device_get(found).items()})
+        return out
+
     def snapshot_state(self) -> dict:
         with self._lock:
             return jax.device_get({"states": self.states,
@@ -1201,7 +1215,13 @@ class PatternQueryRuntime(QueryRuntime):
         with self._lock:
             self.states = self._restored_states(snap["states"])
             self._emitted_dev = jnp.array(snap["emitted"], copy=True)
-            self.nfa_state = _fresh_device(snap["nfa"])
+            nfa = snap["nfa"]
+            # a snapshot taken before a counter existed restores it at 0
+            init = self.engine.init_state()
+            if init.keys() - nfa.keys():
+                nfa = {**{k: init[k] for k in init.keys() - nfa.keys()},
+                       **nfa}
+            self.nfa_state = _fresh_device(nfa)
             self._sched_due = None
 
     def reschedule(self) -> None:
@@ -1279,7 +1299,8 @@ class PatternQueryRuntime(QueryRuntime):
             schema = self.app.schemas[stream_id]
 
             def run(nfa_state, sel_states, tstates, batch, now):
-                nfa_state, match = nfa_step(nfa_state, batch, now)
+                with op_scope(f"{self.name}/nfa"):
+                    nfa_state, match = nfa_step(nfa_state, batch, now)
                 new_sel = []
                 for op, st in zip(sel_ops, sel_states):
                     if op.needs_tables:
@@ -3805,24 +3826,27 @@ class Planner:
         ca = A.find_annotation(q.annotations, "cap")
         if ca is None:
             return None, None, None, None
+        return tuple(_cap_int(ca, key) for key in (
+            "window.size", "join.pairs", "join.candidates", "group.keys"))
 
-        def to_int(v, key):
-            if v is None:
-                return None
-            try:
-                n = int(v)
-            except ValueError:
-                raise CompileError(
-                    f"@cap({key}='{v}'): expected a positive integer")
-            if n <= 0:
-                raise CompileError(
-                    f"@cap({key}='{v}'): expected a positive integer")
-            return n
-
-        return (to_int(ca.element("window.size"), "window.size"),
-                to_int(ca.element("join.pairs"), "join.pairs"),
-                to_int(ca.element("join.candidates"), "join.candidates"),
-                to_int(ca.element("group.keys"), "group.keys"))
+    @staticmethod
+    def _pattern_caps(q: A.Query) -> tuple:
+        """`@cap(pattern.pending='N', pattern.matches='M')` — rows of the
+        parallel NFA engine's pending-match table, and matches it emits
+        per step (defaults 4,096 and 16,384; at most
+        ``ParallelNfaEngine.MAX_PENDING`` rows). Partials past the table
+        and matches past M are dropped and counted in ``overflow``."""
+        ca = A.find_annotation(q.annotations, "cap")
+        caps = [None, None] if ca is None else \
+            [_cap_int(ca, key) for key in ("pattern.pending",
+                                           "pattern.matches")]
+        pending, matches = (d if c is None else c
+                            for c, d in zip(caps, (4096, 16384)))
+        if pending > ParallelNfaEngine.MAX_PENDING:
+            raise CompileError(
+                f"@cap(pattern.pending='{pending}'): at most "
+                f"{ParallelNfaEngine.MAX_PENDING} rows")
+        return pending, matches
 
     def plan_join_query(self, q: A.Query, name: str) -> None:
         app = self.app
@@ -4021,12 +4045,13 @@ class Planner:
         if sel.having is not None:
             sel.having = rewrite_oob_refs(
                 rewrite_last_refs(sel.having, slots), slots)
+        pending, matches = self._pattern_caps(q)
         if parallel_supported(slots, states, sin.state_type):
-            # the TPU-shaped round-parallel engine (larger pending table —
-            # its grids are cheap; the scan engine stays small)
+            # the TPU-shaped round-parallel engine (larger pending table;
+            # the scan engine stays small)
             engine = ParallelNfaEngine(slots, states, sin.state_type,
-                                       sin.within_ms, capacity=4096,
-                                       out_capacity=16384)
+                                       sin.within_ms, capacity=pending,
+                                       out_capacity=matches)
         else:
             engine = NfaEngine(slots, states, sin.state_type,
                                sin.within_ms)
@@ -4057,6 +4082,20 @@ class Planner:
                     out.target, tj, app)
             qr.output_handlers.append(InsertIntoStreamHandler(tj, out_type))
         self.attach_rate_limiter(qr, q, name)
+
+
+def _cap_int(ca, key: str) -> Optional[int]:
+    """A `@cap` element as a positive integer, or None when absent."""
+    v = ca.element(key)
+    if v is None:
+        return None
+    try:
+        n = int(v)
+    except ValueError:
+        n = 0
+    if n <= 0:
+        raise CompileError(f"@cap({key}='{v}'): expected a positive integer")
+    return n
 
 
 def _expect(params, n, name):

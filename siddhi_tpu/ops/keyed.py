@@ -121,6 +121,34 @@ def cumsum_fast(vals):
     return jax.lax.associative_scan(jnp.add, vals, axis=0)
 
 
+RUNNING_COUNT_LIMIT = 2 ** 24   # running_count is exact below this length
+
+
+def running_count(mask):
+    """Inclusive running count of a [n] bool mask, as int32.
+
+    A prefix sum as triangular matmuls over blocks of 128, recursing on
+    the block totals; exact for n below RUNNING_COUNT_LIMIT, where every
+    count fits f32's 24-bit significand and the products are taken at
+    full f32 precision. Callers bound n at plan time. At 1,048,576 rows the TPU
+    compiler takes ~150 s over ``cumsum_fast`` and ~35 s over
+    ``jnp.cumsum``; these few dots compile in seconds."""
+    return _block_prefix(mask.astype(jnp.float32)).astype(jnp.int32)
+
+
+def _block_prefix(x, w: int = 128):
+    n = x.shape[0]
+    if n <= w:
+        return cumsum_fast(x)
+    rows = -(-n // w)
+    blocks = jnp.pad(x, (0, rows * w - n)).reshape(rows, w)
+    tri = jnp.triu(jnp.ones((w, w), x.dtype))   # tri[i, j] = i <= j
+    inner = jnp.matmul(blocks, tri, precision=jax.lax.Precision.HIGHEST)
+    total = inner[:, -1]
+    before = _block_prefix(total, w) - total
+    return (inner + before[:, None]).reshape(rows * w)[:n]
+
+
 def cummax_fast(vals):
     """Inclusive running max via associative_scan — the cumsum_fast twin.
     jax.lax.cummax on int64 did not compile within 80 s at 65536 rows

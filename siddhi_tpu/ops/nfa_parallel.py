@@ -9,19 +9,33 @@ the SAME state evolution with a fixed number of vectorized rounds:
   - each pending row's trajectory through a batch is independent of every
     other row's (the reference's StateEvents never interact either:
     StreamPreStateProcessor.java:364-403 iterates them independently), so
-    rows advance in parallel over a [rows, events] grid;
+    rows advance in parallel;
   - per round, a row at state s finds the FIRST eligible event satisfying
-    s's condition (argmax over the grid row) and advances; R rounds cover
-    any chain of R states consuming the same stream;
+    s's condition and advances; R rounds cover any chain of R states
+    consuming the same stream. A state no row can wait at (an
+    always-armed start) runs no round;
   - counting states (A<m:n>, A+) absorb ALL their eligible matching events
     in one round with a per-row cumulative-sum placement;
   - in-batch spawns from an always-armed start state form a second
     population (one candidate row per event) that advances through the
     same rounds and is folded into the pending table at the end.
 
-Round count = number of states consuming the stream — typically 2-6 — so a
-65k-event batch costs a few [rows, 4096] grid passes instead of 65k
-sequential steps.
+A round finds the first matching event one of two ways, chosen from the
+state's condition at plan time (``round_key``):
+
+  - keyed: the condition has an equality conjunct between the incoming
+    event and a captured slot (``vid == e1.vid``). The sub-batch's events
+    are sorted once by a hash of the event sides, with the event index as
+    tiebreak (``sorted_key_view``); each row finds its band of same-hash
+    events with one searchsorted, starts after its ``last`` and walks the
+    band until an entry passes ``within`` and the WHOLE condition (so a
+    hash collision never matches). O((M + B) log B) per round for M rows
+    and B events, times the longest walk;
+  - grid: every other state evaluates its condition on a [rows, events]
+    grid, O(M * B) per round, over sub-batches of at most PB events.
+
+A step whose rounds are all keyed takes sub-batches of as many events as
+the table has rows, between PB and KB.
 
 Supported shapes (the planner falls back to the scan engine otherwise —
 `parallel_supported` below): linear chains of stream/count states, pattern
@@ -36,21 +50,43 @@ Semantics parity with the scan engine (and the reference) is bit-exact
 except under overflow pressure: the scan engine frees completed rows
 mid-batch event-by-event, this engine allocates spawned survivors at batch
 end, so a saturated table drops (and counts) more re-arms here.
+
+The table carries counters the step accumulates on the device and only
+``PatternQueryRuntime.stats()`` reads (``COUNTERS``).
 """
 from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.event import CURRENT, EventBatch
-from ..core.types import np_dtype
+from ..core.types import AttrType, NUMERIC_TYPES, np_dtype, promote
 from ..lang import ast as A
-from .expr import Col
-from .keyed import cumsum_fast
-from .nfa import NfaEngine, NfaStateSpec, POS_INF, SlotSpec
+from .expr import Col, CompileError, CompiledExpr, Scope, compile_expression
+from .join import _flatten_and
+from .keyed import RUNNING_COUNT_LIMIT, hash_columns, running_count
+from .nfa import (NfaEngine, NfaStateSpec, POS_INF, PatternScope, SlotSpec,
+                  _map_children)
+from .table import sorted_key_view
 
 BIG = np.int32(2 ** 30)  # numpy, not jnp: see ops/sentinels.py
+NO_EMIT = np.int64(2 ** 62)  # emission key of a row that emits nothing
+
+# device counters in the table, read by PatternQueryRuntime.stats():
+# steps; valid events consumed; live rows summed over steps at a step's
+# start and end; the most live rows after any sub-step; rows spawned
+# alive; rows that completed (or a sequence mismatch killed) in the
+# rounds; rows `within` pruned; row x event pairs whose condition a
+# round evaluated. Per step, live_in + spawned - completed - pruned -
+# rows the fold dropped (counted in `overflow`) = live_out.
+COUNTERS = ("nfa_steps", "nfa_events", "nfa_live_in", "nfa_live_out",
+            "nfa_live_peak", "nfa_spawned", "nfa_completed", "nfa_pruned",
+            "nfa_candidates")
 
 
 def _cond_refs_own_indexed(st: NfaStateSpec, slots: list[SlotSpec]) -> bool:
@@ -78,21 +114,8 @@ def _cond_refs_own_indexed(st: NfaStateSpec, slots: list[SlotSpec]) -> bool:
     return bool(found)
 
 
-def parallel_supported(slots: list[SlotSpec],
-                       states: list[NfaStateSpec],
-                       state_type: str = "pattern") -> bool:
-    """Can the batch-parallel engine run this compiled chain?"""
-    # logical groups and absent states run on the scan engine
-    if any(st.partner >= 0 or st.is_absent for st in states):
-        return False
-    # sequences with armed-once starts need the scan engine's per-round
-    # pending lifecycle (one-shot starts, cross-stream staleness —
-    # SequenceMultiProcessStreamReceiver.stabilizeStates); counting-start
-    # sequences keep the parallel path (their absorb lifecycle is exempt)
-    if state_type == "sequence" and any(
-            st.armed_once or st.rearm_each_round for st in states):
-        return False
-    # rows-at-state reachability (which states ever hold table rows)
+def _waiting_states(states: list[NfaStateSpec]) -> set:
+    """Indices of the states at which table rows can ever wait."""
     reach = set()
     for st in states:
         if st.armed_once:
@@ -110,6 +133,24 @@ def parallel_supported(slots: list[SlotSpec],
                     and st.next_idx not in reach:
                 reach.add(st.next_idx)
                 changed = True
+    return reach
+
+
+def parallel_supported(slots: list[SlotSpec],
+                       states: list[NfaStateSpec],
+                       state_type: str = "pattern") -> bool:
+    """Can the batch-parallel engine run this compiled chain?"""
+    # logical groups and absent states run on the scan engine
+    if any(st.partner >= 0 or st.is_absent for st in states):
+        return False
+    # sequences with armed-once starts need the scan engine's per-round
+    # pending lifecycle (one-shot starts, cross-stream staleness —
+    # SequenceMultiProcessStreamReceiver.stabilizeStates); counting-start
+    # sequences keep the parallel path (their absorb lifecycle is exempt)
+    if state_type == "sequence" and any(
+            st.armed_once or st.rearm_each_round for st in states):
+        return False
+    reach = _waiting_states(states)
     for st in states:
         if st.every_arm >= 0:
             # live re-arm edge? dead iff no rows ever reach this state, or
@@ -127,6 +168,152 @@ def parallel_supported(slots: list[SlotSpec],
     return True
 
 
+# ---------------------------------------------------------------------------
+# keyed rounds: equality conjuncts between the event and a captured slot
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class RoundKey:
+    """The equality conjuncts of one state's condition that pair the
+    incoming event with captured slots (``vid == e1.vid``): both sides of
+    each are cast to the type the conjunct compares in and hashed
+    together. States whose event sides read the same (``signature``)
+    share one sorted view of a sub-batch."""
+
+    event: tuple       # CompiledExpr over the incoming event
+    row: tuple         # CompiledExpr over the row's captured slots
+    dtypes: tuple      # numpy dtype each conjunct compares in
+    signature: tuple
+
+
+class _SlotRecorder(Scope):
+    """Wraps a PatternScope and records the slot keys an expression's
+    variables resolve to."""
+
+    def __init__(self, base: Scope):
+        self.base = base
+        self.keys: list = []
+
+    def resolve(self, var: A.Variable):
+        key, t = self.base.resolve(var)
+        self.keys.append(key)
+        return key, t
+
+
+def _side(keys: list, own: int) -> Optional[str]:
+    """'event' when every reference is the incoming event (the state's
+    own slot, current copy), 'row' when none is the own slot."""
+    if not keys:
+        return None
+    if all(j == own and c == 0 for _, j, _, c in keys):
+        return "event"
+    if all(j != own for _, j, _, _ in keys):
+        return "row"
+    return None
+
+
+def _key_dtype(a: AttrType, b: AttrType):
+    if a in NUMERIC_TYPES and b in NUMERIC_TYPES:
+        return np.dtype(np_dtype(promote(a, b)))
+    if a is b and a in (AttrType.STRING, AttrType.BOOL):
+        return np.dtype(np_dtype(a))
+    return None
+
+
+def _unref(e):
+    """The expression with stream references dropped: an event side reads
+    the incoming event whichever name the state gives it."""
+    if isinstance(e, A.Variable):
+        return dataclasses.replace(e, stream_ref=None)
+    return _map_children(e, _unref)
+
+
+def round_key(st: NfaStateSpec, slots: list[SlotSpec]) -> Optional[RoundKey]:
+    """The keyed form of a plain state's condition, or None where it has
+    no equality conjunct between the incoming event and a captured slot
+    (such a state runs the grid)."""
+    if st.cond_ast is None or st.is_counting:
+        return None
+    ev, row, dts, sig = [], [], [], []
+    for conj in _flatten_and(st.cond_ast):
+        if not (isinstance(conj, A.Compare) and conj.op == "=="):
+            continue
+        sides = {}
+        for e in (conj.left, conj.right):
+            rec = _SlotRecorder(PatternScope(slots, own_slot=st.slot))
+            try:
+                ce = compile_expression(e, rec)
+            except CompileError:
+                break
+            sides[_side(rec.keys, st.slot)] = (ce, e)
+        if set(sides) != {"event", "row"}:
+            continue
+        (ce_e, ast_e), (ce_r, _) = sides["event"], sides["row"]
+        dt = _key_dtype(ce_e.type, ce_r.type)
+        if dt is None:
+            continue
+        ev.append(ce_e)
+        row.append(ce_r)
+        dts.append(dt)
+        sig.append((repr(_unref(ast_e)), dt.str))
+    if not ev:
+        return None
+    return RoundKey(tuple(ev), tuple(row), tuple(dts),
+                    (st.stream_id,) + tuple(sig))
+
+
+def _high32(h):
+    return (h >> 32).astype(jnp.int32)
+
+
+def _key_hash(exprs, dtypes, env, shape):
+    """-> ([shape] int64 hash of the key parts, [shape] any part null)."""
+    vals, nulls = [], []
+    for ce, dt in zip(exprs, dtypes):
+        c = ce.fn(env)
+        v = jnp.broadcast_to(c.values, shape).astype(dt)
+        if np.issubdtype(dt, np.floating):
+            v = jnp.where(v == 0, jnp.zeros((), dt), v)   # -0.0 == 0.0
+        vals.append(v)
+        nulls.append(jnp.broadcast_to(c.nulls, shape))
+    return hash_columns(vals, nulls), functools.reduce(jnp.logical_or, nulls)
+
+
+def _lexsort32(key, seq):
+    """Positions in (key, seq) order, for keys below 2**31 or NO_EMIT and
+    non-negative int64 seqs, sorted on 32-bit parts (a 64-bit sort key
+    takes the TPU compiler minutes at tens of thousands of rows)."""
+    k = jnp.minimum(key, np.int64(2 ** 31 - 1)).astype(jnp.int32)
+    return jnp.lexsort(((seq & 0xFFFFFFFF).astype(jnp.uint32),
+                        (seq >> 32).astype(jnp.int32), k)).astype(jnp.int32)
+
+
+def _nth_true(mask, K: int):
+    """[K] positions of the first K set entries of ``mask``, in order;
+    len(mask) where there are fewer. A search over the running count
+    (no scatter, so no sort of indices)."""
+    count = running_count(mask)
+    return jnp.searchsorted(count, jnp.arange(1, K + 1, dtype=jnp.int32),
+                            side="left").astype(jnp.int32)
+
+
+def _concat_pops(a, b):
+    """One population of ``a``'s rows then ``b``'s (the fields ``a``
+    has)."""
+    return jax.tree_util.tree_map(lambda x, y: jnp.concatenate([x, y]), a,
+                                  {k: v for k, v in b.items() if k in a})
+
+
+def _split_pops(pop, m: int, born_rel):
+    """Undo ``_concat_pops``: the first ``m`` rows, then the rest (which
+    get back their ``born_rel``)."""
+    def cut(tree, lo, hi):
+        return jax.tree_util.tree_map(lambda x: x[lo:hi], tree)
+    n = pop["state"].shape[0]
+    return cut(pop, 0, m), {**cut(pop, m, n), "born_rel": born_rel}
+
+
 def _first_true(mask):
     """[P, B] bool -> ([P] first-true index (0 if none), [P] any)."""
     j = jnp.argmax(mask, axis=1).astype(jnp.int32)
@@ -136,18 +323,50 @@ def _first_true(mask):
 class ParallelNfaEngine(NfaEngine):
     """Same table pytree, match schema, and outputs as NfaEngine; only the
     per-stream step is rebuilt round-parallel. Sub-batches of at most PB
-    events bound the [rows, events] grid size."""
+    events bound the [rows, events] grid of a grid round; a keyed walk
+    examines one band entry per row per iteration."""
 
     PB = 4096
+    KB = 65536
+    # the fold and the emission order count over the table's rows and a
+    # sub-batch's (at most KB): running_count must stay exact
+    MAX_PENDING = RUNNING_COUNT_LIMIT - KB - 1
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
+        self._waiting = _waiting_states(self.states)
+        # plan-time choice per state: keyed when the condition pairs the
+        # event with a captured slot by equality, grid otherwise
+        self.keys = {st.idx: round_key(st, self.slots)
+                     for st in self.states
+                     if self.state_type == "pattern"}
+
+    def init_state(self):
+        table = super().init_state()
+        for name in COUNTERS:
+            table[name] = jnp.int64(0)
+        return table
+
+    def _keyed_sub_batch(self) -> int:
+        """Events per sub-batch of a step whose rounds are all keyed: as
+        many as the table has rows, between PB and KB. A sub-step sweeps
+        the whole table, so a large table takes large sub-batches; a
+        small one keeps its event sort small (the TPU compiler's time
+        for a sort grows with its length)."""
+        return min(max(self.M, self.PB), self.KB)
+
+    def round_kind(self, st: NfaStateSpec) -> str:
+        """'keyed' or 'grid': how a round at ``st`` finds each row's first
+        matching event."""
+        return "grid" if self.keys.get(st.idx) is None else "keyed"
 
     # -- env builders ----------------------------------------------------
-    def _env_grid(self, pop, ev_cols, ev_nulls, own_slot: int, B: int):
-        """Env of [P, B]-broadcastable Cols: row captures [P,1] against
-        event values [1,B]; own slot's current view = incoming event.
-        Unused entries are dead-code-eliminated by XLA."""
+    def _env_pairs(self, pop, ev_cols, ev_nulls, own_slot: int, shape):
+        """Env of Cols broadcastable to ``shape`` = [P, X]: row captures
+        [P, 1] against event values already shaped to broadcast ([1, B]
+        for the grid, the [P, W] entries of a keyed walk); the own slot's
+        current view is the incoming event. Unused entries are
+        dead-code-eliminated by XLA."""
         env = {}
         for j, spec in enumerate(self.slots):
             buf = pop["slots"][j]
@@ -158,8 +377,8 @@ class ParallelNfaEngine(NfaEngine):
                     nulls = buf["nulls"][a][:, c][:, None]
                     if j == own_slot:
                         at_n = (n == c)[:, None]
-                        vals = jnp.where(at_n, ev_cols[a][None, :], vals)
-                        nulls = jnp.where(at_n, ev_nulls[a][None, :], nulls)
+                        vals = jnp.where(at_n, ev_cols[a], vals)
+                        nulls = jnp.where(at_n, ev_nulls[a], nulls)
                     env[("slot", j, a, c)] = Col(vals, nulls)
             n_eff = n + (1 if j == own_slot else 0)
             for a in range(len(spec.schema.types)):
@@ -170,10 +389,8 @@ class ParallelNfaEngine(NfaEngine):
                     nulls = jnp.take_along_axis(
                         buf["nulls"][a], pos[:, None], axis=1)
                     if j == own_slot and k == 0:
-                        vals = jnp.broadcast_to(
-                            ev_cols[a][None, :], (n.shape[0], B))
-                        nulls = jnp.broadcast_to(
-                            ev_nulls[a][None, :], (n.shape[0], B))
+                        vals = jnp.broadcast_to(ev_cols[a], shape)
+                        nulls = jnp.broadcast_to(ev_nulls[a], shape)
                     env[("slot_last", j, a, k)] = Col(vals, nulls)
         return env
 
@@ -248,44 +465,159 @@ class ParallelNfaEngine(NfaEngine):
             for k, b in enumerate(pop["slots"]))}
 
     # -- the round engine ------------------------------------------------
-    def _advance_rounds(self, pop, ev, consuming, B: int):
-        """One pass over the consuming states IN CHAIN ORDER advances every
-        row as far as it can go in this batch: linear chains compile to
-        increasing state indices, so a row that advances at state k is
-        picked up again by the state-(k+1) round with eligibility starting
-        after its captured event. ev = (ts, kind, valid, cols, nulls)."""
+    def _advance_rounds(self, pop, ev, rounds, views, B: int):
+        """One pass over the states rows can wait at, IN CHAIN ORDER,
+        advances every row as far as it can go in this batch: linear
+        chains compile to increasing state indices, so a row that advances
+        at state k is picked up again by the state-(k+1) round with
+        eligibility starting after its captured event. ev = (ts, kind,
+        valid, cols, nulls); ``views`` holds the sub-batch's sorted view
+        per key signature. Returns (pop, candidates evaluated)."""
         ev_ts, ev_kind, ev_valid, ev_cols, ev_nulls = ev
         idx_b = jnp.arange(B, dtype=jnp.int32)
         is_cur = ev_valid & (ev_kind == CURRENT)
         seqmode = self.state_type == "sequence"
+        cands = jnp.int64(0)
+        for st in rounds:
+            personas = [cs for cs in self.states
+                        if cs.is_counting and cs.next_idx == st.idx]
+            pop, n = self._state_round(
+                pop, st, personas, ev, is_cur, idx_b, B, seqmode, views)
+            cands = cands + n
+        return pop, cands
 
-        persona_sources = {
-            st.idx: [cs for cs in self.states
-                     if cs.is_counting and cs.next_idx == st.idx]
-            for st in consuming}
+    def _within_ok(self, pop, ts):
+        """Rows [P, 1] x event times ``ts`` (broadcastable to [P, X]):
+        inside ``within`` of the row's first capture, inclusive."""
+        if self.within_ms is None:
+            return jnp.ones((), jnp.bool_)
+        ok = jnp.abs(ts - pop["ts0"][:, None]) <= self.within_ms
+        return ~pop["has_ts0"][:, None] | ok
 
-        for st in consuming:
-            pop = self._state_round(
-                pop, st, persona_sources[st.idx], ev_ts, ev_cols,
-                ev_nulls, is_cur, idx_b, B, seqmode)
-        return pop
-
-    def _eligible(self, pop, is_cur, idx_b, ev_ts, B):
-        # pending-table grid: bounded by the pattern capacity dial,
-        # and the round-parallel engine's whole design point (its grids
-        # are cheap — see parallel_supported)
+    def _eligible(self, pop, is_cur, idx_b, ev_ts):
+        # pending-table grid: bounded by PB, and only for states with no
+        # equality conjunct to key on (see round_key)
         elig = (
             is_cur[None, :]  # lint: disable=quadratic-grid-hazard
             & (idx_b[None, :] > pop["last"][:, None]))
-        if self.within_ms is not None:
-            ok = (
-                jnp.abs(ev_ts[None, :] - pop["ts0"][:, None])  # lint: disable=quadratic-grid-hazard
-                <= self.within_ms)
-            elig = elig & (~pop["has_ts0"][:, None] | ok)
-        return elig
+        return elig & self._within_ok(pop, ev_ts[None, :])
 
-    def _state_round(self, pop, st, personas, ev_ts, ev_cols, ev_nulls,
-                     is_cur, idx_b, B, seqmode):
+    @staticmethod
+    def _cond_ok(st, env, shape):
+        if st.cond is None:
+            return jnp.ones(shape, jnp.bool_)
+        c = st.cond.fn(env)
+        return jnp.broadcast_to(c.values & ~c.nulls, shape)
+
+    def _key_view(self, st, rk: RoundKey, ev, B: int):
+        """The sub-batch's events sorted by (key hash, event index):
+        (order, sorted hashes, live count). The hash is cut to its high
+        32 bits, as the sort keys are all 32-bit (a 64-bit sort key at
+        65,536 events takes the TPU compiler minutes); events that are
+        not CURRENT or whose key is null sort last."""
+        _, ev_kind, ev_valid, ev_cols, ev_nulls = ev
+        h, null = _key_hash(rk.event, rk.dtypes,
+                            self._virtual_env_b(st, ev_cols, ev_nulls), (B,))
+        live = ev_valid & (ev_kind == CURRENT) & ~null
+        order, sk, n_live = sorted_key_view(_high32(h), live)
+        order = order.astype(jnp.int32)
+        # where each position's run of equal hashes ends, and where each
+        # event sits in the sorted order
+        end = jnp.minimum(jnp.searchsorted(sk, sk, side="right")
+                          .astype(jnp.int32), n_live)
+        at = jnp.zeros((B,), jnp.int32).at[order].set(
+            jnp.arange(B, dtype=jnp.int32), unique_indices=True)
+        return {"order": order, "keys": sk, "live": n_live, "end": end,
+                "at": at}
+
+    def _keyed_first(self, pop, st, rk: RoundKey, view, at_rows, ev,
+                     B: int):
+        """Each row's first matching event by key: the row's band of
+        same-hash events (index order within it) from its first event
+        after ``last``, walked one entry per iteration
+        until every row has a match or has left its band — a long band
+        takes more iterations, never a cut. Each entry walked is checked
+        against ``within`` and the state's whole condition; where the
+        sub-batch's event time never decreases, an entry past ``within``
+        ends the walk too (and is not counted). Returns (j, found,
+        entries walked); an empty band walks none."""
+        ev_ts, _, ev_valid, ev_cols, ev_nulls = ev
+        order = view["order"]
+        deadline = None
+        if self.within_ms is not None:
+            # event time in order: the valid events are a prefix and
+            # never go back (else no walk ends early)
+            in_order = jnp.all(ev_valid[1:] <= ev_valid[:-1]) & jnp.all(
+                ~ev_valid[1:] | (ev_ts[1:] >= ev_ts[:-1]))
+            deadline = jnp.where(pop["has_ts0"] & in_order,
+                                 pop["ts0"] + self.within_ms, POS_INF)
+        P = at_rows.shape[0]
+        # the row side reads captured slots only: no own slot, no events
+        h, null = _key_hash(rk.row, rk.dtypes,
+                            self._env_pairs(pop, (), (), -1, (P, 1)), (P, 1))
+        h, active = h[:, 0], at_rows & ~null[:, 0]
+        # the row's band: one search for its start, its end from the view
+        key = _high32(h)
+        lo = jnp.minimum(jnp.searchsorted(view["keys"], key, side="left")
+                         .astype(jnp.int32), view["live"])
+        at_lo = jnp.minimum(lo, B - 1)
+        hi = jnp.where(active & (lo < view["live"]) &
+                       (view["keys"][at_lo] == key), view["end"][at_lo], lo)
+        # the band's first entry after `last`: next to `last` itself where
+        # that event lies in the band (it does where both sides of the key
+        # read the same values, vid == e1.vid); else a binary search,
+        # run only while some row needs it
+        last = pop["last"]
+        q = view["at"][jnp.clip(last, 0, B - 1)]
+        inside = (last >= 0) & (q >= lo) & (q < hi)
+        search = (last >= 0) & ~inside & (lo < hi)
+
+        def searching(c):
+            lo, top = c
+            return jnp.any(search & (lo < top))
+
+        def halve(c):
+            lo, top = c
+            mid = (lo + top) // 2
+            right = order[jnp.minimum(mid, B - 1)] <= last
+            more = search & (lo < top)
+            return (jnp.where(more & right, mid + 1, lo),
+                    jnp.where(more & ~right, mid, top))
+
+        found_lo, _ = jax.lax.while_loop(searching, halve, (lo, hi))
+        p0 = jnp.where(inside, q + 1, jnp.where(search, found_lo, lo))
+
+        def walking(c):
+            return jnp.any(~c[1])
+
+        def walk(c):
+            p, done, j, found, walked = c
+            inband = p < hi
+            e = order[jnp.minimum(p, B - 1)]
+            env = self._env_pairs(pop, [x[e][:, None] for x in ev_cols],
+                                  [x[e][:, None] for x in ev_nulls],
+                                  st.slot, (P, 1))
+            ok = inband & (self._cond_ok(st, env, (P, 1)) &
+                           self._within_ok(pop, ev_ts[e][:, None]))[:, 0]
+            if deadline is not None:
+                # a later entry of the band is no earlier: past the
+                # deadline, nothing after this entry can match either
+                inband = inband & (ev_ts[e] <= deadline)
+            live = ~done
+            walked = walked + jnp.sum(live & (ok | inband), dtype=jnp.int64)
+            hit = live & ok
+            j = jnp.where(hit, e, j)
+            return (p + 1, done | ~inband | ok, j, found | hit, walked)
+
+        _, _, j, found, walked = jax.lax.while_loop(
+            walking, walk,
+            (p0, ~active, jnp.zeros((P,), jnp.int32),
+             jnp.zeros((P,), jnp.bool_), jnp.int64(0)))
+        return j, found, walked
+
+    def _state_round(self, pop, st, personas, ev, is_cur, idx_b, B,
+                     seqmode, views):
+        ev_ts, _, _, ev_cols, ev_nulls = ev
         P = pop["state"].shape[0]
         normal = pop["valid"] & (pop["state"] == st.idx)
         persona = jnp.zeros((P,), jnp.bool_)
@@ -295,36 +627,42 @@ class ParallelNfaEngine(NfaEngine):
                 (pop["slots"][cs.slot]["n"] >= cs.min_count) &
                 pop["min_prev"])
         at_rows = normal | persona
-        # cheap short-circuit is not possible under jit; grids are DCE'd
-        env = self._env_grid(pop, ev_cols, ev_nulls, st.slot, B)
-        if st.cond is not None:
-            c = st.cond.fn(env)
-            cond_ok = jnp.broadcast_to(c.values & ~c.nulls, (P, B))
-        else:
-            cond_ok = jnp.ones((P, B), jnp.bool_)
-        elig = self._eligible(pop, is_cur, idx_b, ev_ts, B)
-
-        if st.is_counting:
-            return self._counting_round(
-                pop, st, at_rows, persona, elig & cond_ok, ev_ts, ev_cols,
-                ev_nulls, B)
-
-        if seqmode:
-            # sequence: a NORMAL row's fate is decided by its first
-            # eligible event (advance on match, die on mismatch); PERSONA
-            # rows test every event and are never sequence-killed
-            # (the scan engine's seq_kill applies to `normal` only)
-            j0, has0 = _first_true(elig)
-            cond_at = jnp.take_along_axis(
-                cond_ok, j0[:, None].astype(jnp.int64), axis=1)[:, 0]
-            jm, hasm = _first_true(elig & cond_ok)
-            adv = (normal & has0 & cond_at) | (persona & hasm)
-            kill = normal & has0 & ~cond_at
-            j = jnp.where(persona, jm, j0)
-        else:
-            j, has = _first_true(elig & cond_ok)
+        rk = self.keys.get(st.idx)
+        kill = jnp.zeros((P,), jnp.bool_)
+        if rk is not None:
+            j, has, cands = self._keyed_first(
+                pop, st, rk, views[rk.signature], at_rows, ev, B)
             adv = at_rows & has
-            kill = jnp.zeros((P,), jnp.bool_)
+        else:
+            cands = jnp.int64(P * B)
+            # cheap short-circuit is not possible under jit; grids are DCE'd
+            env = self._env_pairs(pop, [c[None, :] for c in ev_cols],
+                                  [n[None, :] for n in ev_nulls], st.slot,
+                                  (P, B))
+            cond_ok = self._cond_ok(st, env, (P, B))
+            elig = self._eligible(pop, is_cur, idx_b, ev_ts)
+
+            if st.is_counting:
+                return self._counting_round(
+                    pop, st, at_rows, persona, elig & cond_ok, ev_ts,
+                    ev_cols, ev_nulls, B), cands
+
+            if seqmode:
+                # sequence: a NORMAL row's fate is decided by its first
+                # eligible event (advance on match, die on mismatch);
+                # PERSONA rows test every event and are never
+                # sequence-killed (the scan engine's seq_kill applies to
+                # `normal` only)
+                j0, has0 = _first_true(elig)
+                cond_at = jnp.take_along_axis(
+                    cond_ok, j0[:, None].astype(jnp.int64), axis=1)[:, 0]
+                jm, hasm = _first_true(elig & cond_ok)
+                adv = (normal & has0 & cond_at) | (persona & hasm)
+                kill = normal & has0 & ~cond_at
+                j = jnp.where(persona, jm, j0)
+            else:
+                j, has = _first_true(elig & cond_ok)
+                adv = at_rows & has
 
         pop = self._capture_at(pop, st.slot, jnp.zeros((P,), jnp.int32),
                                ev_cols, ev_nulls, ev_ts, j, adv)
@@ -348,7 +686,7 @@ class ParallelNfaEngine(NfaEngine):
                    "state": jnp.where(adv, jnp.int32(st.next_idx),
                                       pop["state"]),
                    "valid": pop["valid"] & ~kill}
-        return pop
+        return pop, cands
 
     def _counting_round(self, pop, st, at_rows, persona, cand, ev_ts,
                         ev_cols, ev_nulls, B):
@@ -432,7 +770,7 @@ class ParallelNfaEngine(NfaEngine):
     # -- spawns ----------------------------------------------------------
     def _spawn_pop(self, start, ev, B, next_seq):
         """One candidate row per event for the always-armed start state.
-        Returns (pop, n_spawned, emit_only_mask)."""
+        Returns (pop, n_spawned)."""
         ev_ts, ev_kind, ev_valid, ev_cols, ev_nulls = ev
         env = self._virtual_env_b(start, ev_cols, ev_nulls)
         if start.cond is not None:
@@ -444,7 +782,7 @@ class ParallelNfaEngine(NfaEngine):
 
         pop = self._empty_pop(B)
         idx = jnp.arange(B, dtype=jnp.int32)
-        rank = cumsum_fast(hit.astype(jnp.int64)) - 1
+        rank = running_count(hit).astype(jnp.int64) - 1
 
         if start.is_counting:
             min_now = start.min_count <= 1
@@ -520,101 +858,119 @@ class ParallelNfaEngine(NfaEngine):
 
 
     # -- emission / table merge ------------------------------------------
+    def _emission_order(self, key, seq):
+        """Positions of the rows to emit, in (emit_at, seq) order, at most
+        OUT of them: the emitting rows (key below NO_EMIT) in population
+        order, up to OUT (past that they are lost and counted), then
+        sorted, so no sort spans the whole table."""
+        T = key.shape[0]
+        at = _nth_true(key < NO_EMIT, self.OUT)
+        keys = jnp.append(key, NO_EMIT)[at]
+        seqs = jnp.append(seq, jnp.int64(0))[at]
+        return jnp.minimum(at[_lexsort32(keys, seqs)], T - 1)
+
     def _collect_emissions(self, out, pops):
-        """Scatter (emit_at, seq)-ordered emissions from the populations
+        """Write (emit_at, seq)-ordered emissions from the populations
         into the output buffer."""
         OUT = self.OUT
-        keys = []
-        seqs = []
-        fields = []  # (pop, local_index) gathered per emission candidate
-        for pop in pops:
-            P = pop["state"].shape[0]
-            emitting = pop["emit_at"] >= 0
-            keys.append(jnp.where(emitting,
-                                  pop["emit_at"].astype(jnp.int64),
-                                  jnp.int64(2 ** 62)))
-            seqs.append(pop["seq"])
-            fields.append((pop, P))
-        allkey = jnp.concatenate(keys)
-        order = jnp.lexsort((jnp.concatenate(seqs), allkey))
-        T = allkey.shape[0]
-        n_emit = jnp.sum((allkey < 2 ** 62).astype(jnp.int64))
-        dest = out["n"] + jnp.arange(T, dtype=jnp.int64)
-        ok = (jnp.arange(T) < n_emit) & (dest < OUT)
-        d = jnp.where(ok, dest, OUT)
-        lost = jnp.maximum(n_emit - jnp.sum(ok.astype(jnp.int64)), 0)
+        sizes = [pop["state"].shape[0] for pop in pops]
+        allkey = jnp.concatenate([
+            jnp.where(pop["emit_at"] >= 0,
+                      pop["emit_at"].astype(jnp.int64), NO_EMIT)
+            for pop in pops])
+        n_emit = jnp.sum((allkey < NO_EMIT).astype(jnp.int64))
+        order = self._emission_order(
+            allkey, jnp.concatenate([pop["seq"] for pop in pops]))
+        # the emissions land at [n, n + n_emit) of the buffer, cut at OUT:
+        # buffer slot i takes ordered row i - n (a contiguous write)
+        n0 = out["n"]
+        i = jnp.arange(OUT, dtype=jnp.int64)
+        put = (i >= n0) & (i < n0 + n_emit)
+        lost = jnp.maximum(n_emit - (OUT - n0), 0)
 
-        # concatenated per-column sources
+        def place(old, rows):
+            shifted = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([jnp.zeros((OUT,), rows.dtype), rows]),
+                (OUT - n0).astype(jnp.int32), OUT)
+            return jnp.where(put, shifted, old)
+
+        offs = np.cumsum([0] + sizes)
+
+        def gather(arrays):
+            """The K ordered rows of per-population arrays, with no
+            concatenation of whole populations."""
+            got = arrays[0][jnp.clip(order, 0, sizes[0] - 1)]
+            for arr, o, P in zip(arrays[1:], offs[1:], sizes[1:]):
+                got = jnp.where(order >= o, arr[jnp.clip(order - o, 0, P - 1)],
+                                got)
+            return got
+
+        # a final counting slot nulls the copies past emit_n
+        final_counting = {st.slot for st in self.states
+                          if st.next_idx == -1 and st.is_counting}
         cols = list(out["cols"])
         nulls = list(out["nulls"])
         for j, spec in enumerate(self.slots):
             for a in range(len(spec.schema.types)):
                 for c in range(spec.cap):
                     ci = self.col_index[(j, a, c)]
-                    vs, ns = [], []
-                    for pop, P in fields:
-                        buf = pop["slots"][j]
-                        v = buf["cols"][a][:, c]
-                        nl = buf["nulls"][a][:, c]
-                        # final counting slot: null copies >= emit_n
-                        if any(st.next_idx == -1 and st.slot == j
-                               and st.is_counting for st in self.states):
-                            beyond = c >= pop["emit_n"]
-                            nl = nl | beyond
-                        vs.append(v)
-                        ns.append(nl)
-                    src_v = jnp.concatenate(vs)[order]
-                    src_n = jnp.concatenate(ns)[order]
-                    cols[ci] = cols[ci].at[d].set(src_v, mode="drop")
-                    nulls[ci] = nulls[ci].at[d].set(src_n, mode="drop")
-        ts_src = jnp.concatenate(
-            [p["emit_ts"] for p, _ in fields])[order]
-        ts = out["ts"].at[d].set(ts_src, mode="drop")
+                    vs = [pop["slots"][j]["cols"][a][:, c] for pop in pops]
+                    ns = [pop["slots"][j]["nulls"][a][:, c] for pop in pops]
+                    if j in final_counting:
+                        ns = [nl | (c >= pop["emit_n"])
+                              for nl, pop in zip(ns, pops)]
+                    cols[ci] = place(cols[ci], gather(vs))
+                    nulls[ci] = place(nulls[ci], gather(ns))
+        ts = place(out["ts"], gather([p["emit_ts"] for p in pops]))
         return {"cols": tuple(cols), "nulls": tuple(nulls), "ts": ts,
                 "n": out["n"] + jnp.minimum(n_emit, OUT - out["n"]),
                 "lost": out["lost"] + lost}
 
     def _fold_spawns(self, table, pop2, counter, sub_off: int):
-        """Append surviving spawned rows into free table slots (in seq
-        order); overflow counted."""
+        """Append surviving spawned rows into free table slots, lowest
+        free slot first, in seq order; overflow counted. The survivors
+        are gathered to the front, so the slots written ascend and are
+        distinct: the scatters need no sort of their indices."""
         M = self.M
         B = pop2["state"].shape[0]
-        free = ~table["valid"]
-        free_pos = jnp.argsort(~free)
-        n_free = jnp.sum(free.astype(jnp.int32))
         want = pop2["valid"]
-        rank = jnp.cumsum(want.astype(jnp.int32)) - 1
-        ok = want & (rank < n_free)
-        lost = jnp.sum((want & ~ok).astype(jnp.int64))
-        dest = free_pos[jnp.clip(rank, 0, M - 1)]
-        d = jnp.where(ok, dest, M)
+        n_want = jnp.sum(want.astype(jnp.int32))
+        free = ~table["valid"]
+        n_free = jnp.sum(free.astype(jnp.int32))
+        lost = jnp.maximum(n_want - n_free, 0).astype(jnp.int64)
+        r = jnp.arange(B, dtype=jnp.int32)
+        src = jnp.minimum(_nth_true(want, B), B - 1)   # r-th survivor
+        slot = _nth_true(free, B)                      # r-th free slot
+        d = jnp.where(r < jnp.minimum(n_want, n_free), slot, M + r)
 
-        state = table["state"].at[d].set(pop2["state"], mode="drop")
-        valid = table["valid"].at[d].set(True, mode="drop")
-        born = table["born"].at[d].set(
-            counter + (sub_off + pop2["born_rel"]).astype(jnp.int64),
-            mode="drop")
-        seq = table["seq"].at[d].set(pop2["seq"], mode="drop")
-        ts0 = table["ts0"].at[d].set(pop2["ts0"], mode="drop")
-        has_ts0 = table["has_ts0"].at[d].set(pop2["has_ts0"], mode="drop")
-        min_at = table["min_at"].at[d].set(
-            jnp.where(pop2["minrel"] < BIG,
-                      counter + (sub_off + pop2["minrel"]).astype(
-                          jnp.int64),
-                      jnp.int64(-1)), mode="drop")
-        deadline = table["deadline"].at[d].set(POS_INF, mode="drop")
+        def put(col, vals):
+            return col.at[d].set(vals[src], mode="drop",
+                                 indices_are_sorted=True,
+                                 unique_indices=True)
+
+        state = put(table["state"], pop2["state"])
+        valid = put(table["valid"], jnp.ones((B,), jnp.bool_))
+        born = put(table["born"],
+                   counter + (sub_off + pop2["born_rel"]).astype(jnp.int64))
+        seq = put(table["seq"], pop2["seq"])
+        ts0 = put(table["ts0"], pop2["ts0"])
+        has_ts0 = put(table["has_ts0"], pop2["has_ts0"])
+        min_at = put(table["min_at"], jnp.where(
+            pop2["minrel"] < BIG,
+            counter + (sub_off + pop2["minrel"]).astype(jnp.int64),
+            jnp.int64(-1)))
+        deadline = put(table["deadline"], jnp.full((B,), POS_INF))
         slots = []
         for j in range(len(self.slots)):
             tb = table["slots"][j]
             pb = pop2["slots"][j]
             slots.append({
-                "cols": tuple(tc.at[d].set(pc, mode="drop")
+                "cols": tuple(put(tc, pc)
                               for tc, pc in zip(tb["cols"], pb["cols"])),
-                "nulls": tuple(tn.at[d].set(pn, mode="drop")
-                               for tn, pn in zip(tb["nulls"],
-                                                 pb["nulls"])),
-                "ts": tb["ts"].at[d].set(pb["ts"], mode="drop"),
-                "n": tb["n"].at[d].set(pb["n"], mode="drop"),
+                "nulls": tuple(put(tn, pn)
+                               for tn, pn in zip(tb["nulls"], pb["nulls"])),
+                "ts": put(tb["ts"], pb["ts"]),
+                "n": put(tb["n"], pb["n"]),
             })
         return {**table, "state": state, "valid": valid, "born": born,
                 "seq": seq, "ts0": ts0, "has_ts0": has_ts0,
@@ -624,17 +980,35 @@ class ParallelNfaEngine(NfaEngine):
 
     # -- the step --------------------------------------------------------
     def make_stream_step(self, stream_id: str):
-        consuming = [st for st in self.states
-                     if st.stream_id == stream_id]
+        """(table, EventBatch, now) -> (table', match_batch). The step's
+        parts run under the named scopes ``key_sort``, ``rounds``,
+        ``spawn``, ``fold`` and ``prune`` (the runtime adds
+        ``<query>/nfa``)."""
+        rounds = [st for st in self.states
+                  if st.stream_id == stream_id and st.idx in self._waiting]
         starts = [st for st in self.states
                   if st.always_armed and st.stream_id == stream_id]
         start = starts[0] if starts else None
+        # a step whose rounds are all keyed needs no grid bound
+        grid = any(self.keys.get(st.idx) is None for st in rounds)
+        keyed = {}                   # signature -> (state, RoundKey)
+        for st in rounds:
+            rk = self.keys.get(st.idx)
+            if rk is not None:
+                keyed.setdefault(rk.signature, (st, rk))
+
+        def live(table):
+            return jnp.sum(table["valid"].astype(jnp.int64))
 
         def sub_step(table, out, ev, sub_off):
             (ev_ts, ev_kind, ev_valid, ev_cols, ev_nulls) = ev
             B = ev_ts.shape[0]
             counter = table["counter"]
             M = self.M
+
+            with jax.named_scope("key_sort"):
+                views = {sig: self._key_view(st, rk, ev, B)
+                         for sig, (st, rk) in keyed.items()}
 
             # P1: the persistent table as a population. min<0:n> counting
             # states reach their minimum at birth — their rows answer the
@@ -656,41 +1030,52 @@ class ParallelNfaEngine(NfaEngine):
                 "emit_n": jnp.zeros((M,), jnp.int32),
                 "slots": table["slots"],
             }
-            pop1 = self._advance_rounds(
-                pop1, ev, consuming, B)
-
-            pops = [pop1]
-            n_spawned = jnp.int64(0)
+            live_before = live(table)
+            spawned = jnp.int64(0)
             if start is not None:
-                pop2, n_spawned = self._spawn_pop(
-                    start, ev, B, table["next_seq"])
-                pop2 = {**pop2, "min_prev": jnp.zeros((B,), jnp.bool_)}
-                if len(consuming) > 1 or start.is_counting:
-                    pop2 = self._advance_rounds(
-                        pop2, ev, consuming, B)
-                pops.append(pop2)
+                with jax.named_scope("spawn"):
+                    pop2, n_spawned = self._spawn_pop(
+                        start, ev, B, table["next_seq"])
+                    pop2 = {**pop2, "min_prev": jnp.zeros((B,), jnp.bool_)}
+                    spawned = jnp.sum(pop2["valid"].astype(jnp.int64))
+            with jax.named_scope("rounds"):
+                if start is not None and rounds:
+                    # the table's rows and the spawned ones advance
+                    # alike, independently: one population, one program
+                    both, cands = self._advance_rounds(
+                        _concat_pops(pop1, pop2), ev, rounds, views, B)
+                    pop1, pop2 = _split_pops(both, M, pop2["born_rel"])
+                else:
+                    pop1, cands = self._advance_rounds(pop1, ev, rounds,
+                                                       views, B)
+            pops = [pop1] if start is None else [pop1, pop2]
+            completed = live_before + spawned - sum(
+                jnp.sum(p["valid"].astype(jnp.int64)) for p in pops)
 
-            # emission timestamps (per-row gather of emit event ts)
-            for pop in pops:
-                j = jnp.clip(pop["emit_at"], 0, B - 1)
-                pop["emit_ts"] = ev_ts[j]
-            out = self._collect_emissions(out, pops)
+            with jax.named_scope("fold"):
+                # emission timestamps (per-row gather of emit event ts)
+                for pop in pops:
+                    j = jnp.clip(pop["emit_at"], 0, B - 1)
+                    pop["emit_ts"] = ev_ts[j]
+                out = self._collect_emissions(out, pops)
 
-            # within pruning at batch end (monotonic time: a row that
-            # exceeded `within` during this batch can never match again)
+            # within pruning at batch end: event time never goes back, so
+            # a row whose first capture lies more than `within` before the
+            # batch's latest event can never match again. A row born late
+            # in a batch that spans more than `within` lives on.
+            any_valid = jnp.any(ev_valid)
+            tsmax = jnp.max(jnp.where(ev_valid, ev_ts, -POS_INF))
+
             def prune(pop):
                 if self.within_ms is None:
-                    return pop
-                any_valid = jnp.any(ev_valid)
-                tsmax = jnp.max(jnp.where(ev_valid, ev_ts, -POS_INF))
-                tsmin = jnp.min(jnp.where(ev_valid, ev_ts, POS_INF))
-                dist = jnp.maximum(jnp.abs(tsmax - pop["ts0"]),
-                                   jnp.abs(tsmin - pop["ts0"]))
-                dead = pop["has_ts0"] & any_valid & \
-                    (dist > self.within_ms)
-                return {**pop, "valid": pop["valid"] & ~dead}
+                    return pop, jnp.int64(0)
+                dead = pop["valid"] & pop["has_ts0"] & any_valid & \
+                    (tsmax - pop["ts0"] > self.within_ms)
+                return ({**pop, "valid": pop["valid"] & ~dead},
+                        jnp.sum(dead.astype(jnp.int64)))
 
-            pop1 = prune(pop1)
+            with jax.named_scope("prune"):
+                pop1, pruned = prune(pop1)
 
             # write P1 back into the table
             table = {
@@ -706,13 +1091,22 @@ class ParallelNfaEngine(NfaEngine):
                 "slots": pop1["slots"],
             }
             if start is not None:
-                pop2 = prune(pop2)
-                table = self._fold_spawns(table, pop2, counter,
-                                          sub_off)
+                with jax.named_scope("prune"):
+                    pop2, pruned2 = prune(pop2)
+                pruned = pruned + pruned2
+                with jax.named_scope("fold"):
+                    table = self._fold_spawns(table, pop2, counter,
+                                              sub_off)
                 table = {**table,
                          "next_seq": table["next_seq"] + n_spawned}
-            table = {**table, "counter": counter + B}
-            return table, out
+            return {**table, "counter": counter + B,
+                    "nfa_live_peak": jnp.maximum(table["nfa_live_peak"],
+                                                 live(table)),
+                    "nfa_spawned": table["nfa_spawned"] + spawned,
+                    "nfa_completed": table["nfa_completed"] + completed,
+                    "nfa_pruned": table["nfa_pruned"] + pruned,
+                    "nfa_candidates": table["nfa_candidates"] + cands,
+                    }, out
 
         def step(table, batch: EventBatch, now):
             B = batch.capacity
@@ -725,7 +1119,8 @@ class ParallelNfaEngine(NfaEngine):
                 "n": jnp.int64(0),
                 "lost": jnp.int64(0),
             }
-            PB = min(self.PB, B)
+            live_in = live(table)
+            PB = min(self.PB if grid else self._keyed_sub_batch(), B)
             n_sub = (B + PB - 1) // PB
 
             if n_sub == 1:
@@ -754,7 +1149,13 @@ class ParallelNfaEngine(NfaEngine):
                 kind=jnp.zeros((self.OUT,), jnp.int32),
                 valid=jnp.arange(self.OUT) < out["n"],
             )
-            table = {**table, "overflow": table["overflow"] + out["lost"]}
+            events = jnp.sum((batch.valid & (batch.kind == CURRENT))
+                             .astype(jnp.int64))
+            table = {**table, "overflow": table["overflow"] + out["lost"],
+                     "nfa_steps": table["nfa_steps"] + 1,
+                     "nfa_events": table["nfa_events"] + events,
+                     "nfa_live_in": table["nfa_live_in"] + live_in,
+                     "nfa_live_out": table["nfa_live_out"] + live(table)}
             return table, match_batch
 
         return step

@@ -382,7 +382,7 @@ def sorted_key_view(keys, live, xp=None):
     probes with two searchsorteds over this view instead of a [B, T]
     condition grid. ``xp`` selects the array namespace: jnp (default,
     in-trace device use) or numpy — the ingest-side reorder buffer
-    (resilience/ordering.py) runs the SAME pad-last lexsort contract on
+    (resilience/ordering.py) runs the SAME pad-last sort contract on
     host arrays for its in-buffer timestamp ordering."""
     if xp is None:
         xp = jnp
@@ -393,13 +393,22 @@ def sorted_key_view(keys, live, xp=None):
     else:
         big = _np.asarray(_np.iinfo(_np.dtype(keys.dtype.name)).max,
                           keys.dtype.name)
-    # pad-last LEXSORT (pad flag primary): a live row whose key equals the
-    # padding sentinel (dtype max / +inf) must sort BEFORE the padding so
-    # the n_live clamp cannot cut it off
+    # pad-last order in one two-key sort: the position carries the pad
+    # flag (padding at T + position), so it breaks key ties live-first
+    # and then by position. A live row whose key equals the padding
+    # sentinel (dtype max / +inf) thus sorts BEFORE the padding and the
+    # n_live clamp cannot cut it off. A third sort operand costs the TPU
+    # compiler minutes at 65,536 rows.
     ks = xp.where(live, keys, big)
-    order = xp.lexsort((xp.arange(T, dtype=xp.int32), ks,
-                        (~live).astype(xp.int8)))
-    return order, ks[order], xp.sum(live.astype(xp.int32))
+    pos = xp.arange(T, dtype=xp.int32)
+    tag = xp.where(live, pos, pos + T)
+    if xp is jnp:
+        sk, tag = jax.lax.sort((ks, tag), num_keys=2)
+    else:
+        by = xp.lexsort((tag, ks))
+        sk, tag = ks[by], tag[by]
+    return (xp.where(tag >= T, tag - T, tag), sk,
+            xp.sum(live.astype(xp.int32)))
 
 
 def band_bounds(sorted_keys, n_live, values, op, act):

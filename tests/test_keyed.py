@@ -207,3 +207,15 @@ def test_vmapped_tenants_stop_together_with_their_own_answers():
         np.testing.assert_array_equal(new_used[t], r_used)
         assert int(overflow[t]) == int(r_overflow)
     assert rounds.tolist() == [4, 1]
+
+
+@pytest.mark.parametrize("n", [1, 129, 16_385, 263_169])
+def test_running_count_is_the_prefix_sum(n):
+    """The block-matmul running count equals numpy's cumsum, three block
+    levels deep and at block edges, on a sparse and a full mask."""
+    from siddhi_tpu.ops.keyed import running_count
+    rng = np.random.default_rng(n)
+    for mask in (rng.random(n) < 0.1, np.ones(n, bool)):
+        got = np.asarray(running_count(jnp.asarray(mask)))
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, np.cumsum(mask))

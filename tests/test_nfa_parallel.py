@@ -135,3 +135,242 @@ class TestSubBatchedCounting:
             ts + B, [np.full(B, 99, dtype=np.int32)])
         rt.shutdown()
         assert len(got) > 0  # matches produced, no dtype crash
+
+
+# -- keyed rounds: a state whose condition pairs the event with a captured
+# slot by equality finds its first matching event by key ------------------
+
+KEYED_APP = "@app:playback\ndefine stream S (k int, v int);\n"
+
+
+def keyed_ql(states: int, within_ms: int, cap: str = "") -> str:
+    chain = " -> ".join(f"e{i}=S[k == e1.k]" for i in range(2, states + 1))
+    return (f"@info(name='q') {cap}\nfrom every e1=S -> {chain} "
+            f"within {within_ms} millisecond "
+            f"select e1.k as k, e1.v as v1, e{states}.v as vl "
+            "insert into O;")
+
+
+def run_keyed(ql, sends, force_scan=False):
+    import siddhi_tpu.core.runtime as R
+    orig = R.parallel_supported
+    if force_scan:
+        R.parallel_supported = lambda *a: False
+    try:
+        rt = SiddhiManager().create_siddhi_app_runtime(KEYED_APP + ql)
+        got = []
+        rt.add_callback("O", StreamCallback(fn=lambda evs: got.extend(
+            (e.timestamp, *e.data) for e in evs)))
+        rt.start()
+        for ts, cols in sends:
+            rt.get_input_handler("S").send_arrays(ts, cols)
+        stats = rt.queries["q"].stats()
+        rt.shutdown()
+        return got, stats
+    finally:
+        R.parallel_supported = orig
+
+
+def keyed_stream(seed, n, keys, step_ms, tie_every):
+    """n events over ``keys`` keys; time advances by step_ms every
+    ``tie_every`` events, so runs of events share a timestamp."""
+    rng = np.random.default_rng(seed)
+    ts = 1_000_000 + (np.arange(n, dtype=np.int64) // tie_every) * step_ms
+    k = rng.integers(0, keys, n).astype(np.int32)
+    v = np.arange(n, dtype=np.int32)
+    return ts, k, v
+
+
+def chunked(ts, k, v, size):
+    """The stream in chunks of ``size`` events, or of the sizes listed."""
+    sizes = [size] * -(-len(ts) // size) if isinstance(size, int) else size
+    bounds = np.cumsum([0] + sizes)
+    return [(ts[a:b], [k[a:b], v[a:b]]) for a, b in zip(bounds, bounds[1:])]
+
+
+def reference_rows(ts, k, v, states, within_ms):
+    from stopped_reference import consecutive_matches
+    first, last = consecutive_matches(ts, {"k": k}, ("k",), states,
+                                      within_ms)
+    return [(int(ts[b]), int(k[a]), int(v[a]), int(v[b]))
+            for a, b in zip(first.tolist(), last.tolist())]
+
+
+# (states, keys, step_ms, events sharing a timestamp, within_ms): few keys
+# make long bands (~100 same-key events per 512-event chunk); within is a
+# whole number of steps, so matches fall on its bound
+KEYED_CASES = {
+    "2_states_long_bands": (2, 3, 10, 1, 50),
+    "3_states_ties": (3, 5, 100, 7, 300),
+    "4_states_boundary": (4, 4, 30, 1, 90),
+    "5_states_many_keys": (5, 40, 5, 2, 400),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEYED_CASES))
+def test_keyed_rounds_match_scan_and_reference(case):
+    states, keys, step, tie, within = KEYED_CASES[case]
+    ts, k, v = keyed_stream(len(case), 1500, keys, step, tie)
+    ql = keyed_ql(states, within)
+    sends = chunked(ts, k, v, 512)
+    got, stats = run_keyed(ql, sends)
+    want = reference_rows(ts, k, v, states, within)
+    assert len(want) > 0
+    assert got == want
+    assert stats["overflow"] == 0
+    # a keyed round walks a few band entries per row, not rows x events
+    assert stats["nfa_candidates"] <= 4 * (states - 1) * stats["nfa_events"]
+    if states == 4:
+        assert run_keyed(ql, sends, force_scan=True)[0] == want
+        # another split (into the same 512-event bucket) changes nothing
+        assert run_keyed(ql, chunked(ts, k, v, [300, 500, 412, 288]))[0] \
+            == want
+
+
+def test_keyed_rounds_survive_colliding_hashes(monkeypatch):
+    """Every key hashing alike puts every event in every row's band: the
+    whole condition, checked on each entry walked, still decides."""
+    import siddhi_tpu.ops.nfa_parallel as NP
+    monkeypatch.setattr(NP, "hash_columns",
+                        lambda cols, nulls: jnp_zeros_like(cols[0]))
+    states, keys, step, tie, within = KEYED_CASES["3_states_ties"]
+    ts, k, v = keyed_stream(5, 1500, keys, step, tie)
+    got, stats = run_keyed(keyed_ql(states, within), chunked(ts, k, v, 512))
+    assert got == reference_rows(ts, k, v, states, within)
+    # and the walk did cross other keys' events
+    assert stats["nfa_candidates"] > stats["nfa_completed"] * (states - 1)
+
+
+def jnp_zeros_like(x):
+    import jax.numpy as jnp
+    return jnp.zeros(x.shape, jnp.int64)
+
+
+def test_round_kind_is_chosen_from_the_query():
+    rt = SiddhiManager().create_siddhi_app_runtime(
+        KEYED_APP + keyed_ql(4, 90_000) +
+        "\n@info(name='g') from every e1=S -> e2=S[v > e1.v] "
+        "select e1.v as a insert into G;"
+        "\n@info(name='m') from every e1=S -> e2=S[v > e1.v and k == e1.k] "
+        "select e1.v as a insert into M;")
+    eng = rt.queries["q"].engine
+    assert isinstance(eng, ParallelNfaEngine)
+    # the always-armed start holds no row and runs no round
+    assert [eng.round_kind(s) for s in eng.states[1:]] == ["keyed"] * 3
+    assert eng.states[0].idx not in eng._waiting
+    g = rt.queries["g"].engine
+    assert g.round_kind(g.states[1]) == "grid"
+    # a residual conjunct rides along on the key
+    m = rt.queries["m"].engine
+    assert m.round_kind(m.states[1]) == "keyed"
+
+
+def test_pattern_cap_dial():
+    from siddhi_tpu.ops.expr import CompileError
+    cap = "@cap(pattern.pending='64', pattern.matches='8')"
+    rt = SiddhiManager().create_siddhi_app_runtime(
+        KEYED_APP + keyed_ql(2, 1_000_000, cap))
+    eng = rt.queries["q"].engine
+    assert (eng.M, eng.OUT) == (64, 8)
+    default = SiddhiManager().create_siddhi_app_runtime(
+        KEYED_APP + keyed_ql(2, 1_000))
+    assert (default.queries["q"].engine.M,
+            default.queries["q"].engine.OUT) == (4096, 16384)
+    # past MAX_PENDING the fold's running counts would round (f32)
+    for bad in ("0", "-3", "many", str(ParallelNfaEngine.MAX_PENDING + 1)):
+        with pytest.raises(CompileError, match="pattern.pending"):
+            SiddhiManager().create_siddhi_app_runtime(
+                KEYED_APP + keyed_ql(2, 1_000,
+                                     f"@cap(pattern.pending='{bad}')"))
+    # 256 events on 200 keys: more partials than 64 rows, and more
+    # matches in a step than 8: both dropped rows and lost matches count
+    ts, k, v = keyed_stream(1, 256, 200, 1, 1)
+    got, stats = run_keyed(keyed_ql(2, 1_000_000, cap),
+                           chunked(ts, k, v, 128))
+    assert stats["overflow"] > 0
+    assert len(got) < len(reference_rows(ts, k, v, 2, 1_000_000))
+
+
+@pytest.mark.parametrize("pending", [4096, 16])
+def test_counters_add_up(pending):
+    """Per step: live_in + spawned - completed - pruned - dropped =
+    live_out, where rows are dropped only by a full table (overflow, with
+    matches well inside their cap); each completion is one match; and
+    live_out is the table's valid rows."""
+    cap = f"@cap(pattern.pending='{pending}')"
+    ts, k, v = keyed_stream(3, 1024, 16, 20, 1)
+    rt = SiddhiManager().create_siddhi_app_runtime(
+        KEYED_APP + keyed_ql(3, 600, cap))
+    rt.add_callback("O", StreamCallback(fn=lambda evs: None))
+    rt.start()
+    q = rt.queries["q"]
+    h = rt.get_input_handler("S")
+    prev = q.stats()
+    for ts_c, cols in chunked(ts, k, v, 256):
+        h.send_arrays(ts_c, cols)
+        s = q.stats()
+        d = {x: s[x] - prev[x] for x in s if x != "nfa_live_peak"}
+        assert d["nfa_steps"] == 1 and d["nfa_events"] == len(ts_c)
+        assert d["nfa_live_in"] + d["nfa_spawned"] - d["nfa_completed"] \
+            - d["nfa_pruned"] - d["overflow"] == d["nfa_live_out"]
+        assert d["nfa_completed"] == d["emitted"]
+        assert d["nfa_live_out"] == int(np.sum(np.asarray(
+            q.nfa_state["valid"])))
+        assert s["nfa_live_peak"] >= d["nfa_live_out"]
+        prev = s
+    assert (prev["overflow"] > 0) == (pending < 100)
+    # the counters live in the table: a snapshot carries them
+    snap = q.snapshot_state()
+    rt.shutdown()
+    rt2 = SiddhiManager().create_siddhi_app_runtime(
+        KEYED_APP + keyed_ql(3, 600, cap))
+    rt2.queries["q"].restore_state(snap)
+    assert rt2.queries["q"].stats() == {**prev, "emitted": prev["emitted"]}
+    # a snapshot from before the counters restores them at 0
+    old = {**snap, "nfa": {x: y for x, y in snap["nfa"].items()
+                           if not x.startswith("nfa_")}}
+    rt2.queries["q"].restore_state(old)
+    assert rt2.queries["q"].stats()["nfa_steps"] == 0
+
+
+def test_batch_longer_than_within_keeps_late_partials():
+    """The 5-state chain, within 60 s, 16 symbols, 1,024-event chunks at
+    200 ms an event (204.8 s a chunk): a partial opened late in a chunk
+    outlives the chunk's prune."""
+    import collections
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    import chip_smoke as cs
+    ts, sym_idx, stage, v = cs.seq5_data(4096, 16, 200, seed=1)
+    names = cs.sym_names(16)
+    sym = cs.sym_codes(names)[sym_idx]
+    rt = SiddhiManager().create_siddhi_app_runtime(cs.SEQ5_APP)
+    got = []
+    rt.add_callback("Out", StreamCallback(
+        fn=lambda evs: got.extend(tuple(e.data) for e in evs)))
+    rt.start()
+    h = rt.get_input_handler("T")
+    for i in range(0, len(ts), 1024):
+        h.send_arrays(ts[i:i + 1024], [sym[i:i + 1024],
+                                       stage[i:i + 1024], v[i:i + 1024]])
+    stats = rt.queries["q"].stats()
+    rt.shutdown()
+    want = cs.seq5_reference(ts, sym_idx, stage, v, names)
+    g, w = collections.Counter(got), collections.Counter(want)
+    assert len(want) > 0 and stats["overflow"] == 0
+    assert sum((g - w).values()) + sum((w - g).values()) == 0
+
+
+def test_reference_is_the_benchmarks_own():
+    import importlib.util
+    import inspect
+    from pathlib import Path
+    import stopped_reference
+    path = Path(__file__).resolve().parent.parent / "benchmark" / \
+        "configs" / "linear_road_stopped.py"
+    spec = importlib.util.spec_from_file_location("lr_stopped", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert inspect.getsource(mod.consecutive_matches) == \
+        inspect.getsource(stopped_reference.consecutive_matches)

@@ -109,3 +109,34 @@ def test_seq5_step_compiles_for_v5e(one_chip, no_persistent_cache):
     _fits_one_chip(compiled)
     # the step is one program on one chip: no collectives in it
     assert "all-gather" not in compiled.as_text()
+
+
+def _linear_road():
+    """The benchmark's Linear Road configuration and its generator."""
+    import importlib.util
+    import json
+    base = Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+    with open(base / "linear_road_stopped.json") as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "linear_road_stopped", base / "linear_road_stopped.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return config, mod
+
+
+# ~160 s of compile on one core here, more than tier-1's time allows
+@pytest.mark.slow
+def test_linear_road_pattern_step_compiles_for_v5e(one_chip,
+                                                    no_persistent_cache):
+    """The keyed pattern step at the benchmark's size: a pending table of
+    1,048,576 rows against 65,536-report chunks."""
+    import numpy as np
+    config, mod = _linear_road()
+    assert "pattern.pending='1048576'" in config["app"]
+    offsets, cols = mod.generate(np.random.default_rng(0), CHUNK)
+    ts = chip_smoke.TS0 + offsets
+    sample = (ts, [cols[c["name"]] for c in config["columns"]])
+    compiled = _compile_packed_step(config["app"], config["stream"], sample,
+                                    one_chip)
+    _fits_one_chip(compiled)
