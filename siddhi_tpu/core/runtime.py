@@ -1196,8 +1196,9 @@ class PatternQueryRuntime(QueryRuntime):
         """The base counters plus the parallel NFA engine's, read from its
         table in one transfer (ops/nfa_parallel.py ``COUNTERS``): steps,
         events, live rows at step start and end (summed over steps), the
-        live peak, rows spawned, completed and pruned, and the row x event
-        candidates the rounds evaluated."""
+        live peak, rows spawned, completed and pruned, the row x event
+        candidates the rounds evaluated, and the rows keyed rounds placed
+        in a walk block."""
         out = super().stats()
         with self._lock:
             nfa = self.nfa_state

@@ -27,10 +27,15 @@ state's condition at plan time (``round_key``):
     event and a captured slot (``vid == e1.vid``). The sub-batch's events
     are sorted once by a hash of the event sides, with the event index as
     tiebreak (``sorted_key_view``); each row finds its band of same-hash
-    events with one searchsorted, starts after its ``last`` and walks the
-    band until an entry passes ``within`` and the WHOLE condition (so a
-    hash collision never matches). O((M + B) log B) per round for M rows
-    and B events, times the longest walk;
+    events with one searchsorted and its first entry after ``last``
+    (vector ops and one-value gathers over the whole population). Only
+    the rows with an entry left there walk the band, compacted into
+    blocks of WALK_BLOCK rows: a block walks until an entry passes
+    ``within`` and the WHOLE condition (so a hash collision never
+    matches), captures it and scatters back the fields the round
+    changes. O((M + B) log B) per round for M rows and B events, plus
+    the walk and capture of the N rows with an entry left, in
+    ceil(N / WALK_BLOCK) blocks, times the longest walk;
   - grid: every other state evaluates its condition on a [rows, events]
     grid, O(M * B) per round, over sub-batches of at most PB events.
 
@@ -82,11 +87,12 @@ NO_EMIT = np.int64(2 ** 62)  # emission key of a row that emits nothing
 # start and end; the most live rows after any sub-step; rows spawned
 # alive; rows that completed (or a sequence mismatch killed) in the
 # rounds; rows `within` pruned; row x event pairs whose condition a
-# round evaluated. Per step, live_in + spawned - completed - pruned -
-# rows the fold dropped (counted in `overflow`) = live_out.
+# round evaluated; rows a keyed round placed in a walk block. Per step,
+# live_in + spawned - completed - pruned - rows the fold dropped (counted
+# in `overflow`) = live_out.
 COUNTERS = ("nfa_steps", "nfa_events", "nfa_live_in", "nfa_live_out",
             "nfa_live_peak", "nfa_spawned", "nfa_completed", "nfa_pruned",
-            "nfa_candidates")
+            "nfa_candidates", "nfa_walk_rows")
 
 
 def _cond_refs_own_indexed(st: NfaStateSpec, slots: list[SlotSpec]) -> bool:
@@ -289,13 +295,15 @@ def _lexsort32(key, seq):
                         (seq >> 32).astype(jnp.int32), k)).astype(jnp.int32)
 
 
-def _nth_true(mask, K: int):
-    """[K] positions of the first K set entries of ``mask``, in order;
-    len(mask) where there are fewer. A search over the running count
-    (no scatter, so no sort of indices)."""
-    count = running_count(mask)
-    return jnp.searchsorted(count, jnp.arange(1, K + 1, dtype=jnp.int32),
-                            side="left").astype(jnp.int32)
+def _nth_true(mask, K: int, count=None, skip=0):
+    """[K] positions of set entries ``skip`` + 1 to ``skip`` + K of
+    ``mask``, in order; len(mask) where there are fewer. A search over
+    the running count (``count``, where the caller holds it), so no
+    scatter and no sort of indices."""
+    if count is None:
+        count = running_count(mask)
+    want = skip + jnp.arange(1, K + 1, dtype=jnp.int32)
+    return jnp.searchsorted(count, want, side="left").astype(jnp.int32)
 
 
 def _concat_pops(a, b):
@@ -324,10 +332,12 @@ class ParallelNfaEngine(NfaEngine):
     """Same table pytree, match schema, and outputs as NfaEngine; only the
     per-stream step is rebuilt round-parallel. Sub-batches of at most PB
     events bound the [rows, events] grid of a grid round; a keyed walk
-    examines one band entry per row per iteration."""
+    examines one band entry per row per iteration, over blocks of at most
+    WALK_BLOCK rows that have an entry left."""
 
     PB = 4096
     KB = 65536
+    WALK_BLOCK = 4096
     # the fold and the emission order count over the table's rows and a
     # sub-batch's (at most KB): running_count must stay exact
     MAX_PENDING = RUNNING_COUNT_LIMIT - KB - 1
@@ -472,19 +482,20 @@ class ParallelNfaEngine(NfaEngine):
         at state k is picked up again by the state-(k+1) round with
         eligibility starting after its captured event. ev = (ts, kind,
         valid, cols, nulls); ``views`` holds the sub-batch's sorted view
-        per key signature. Returns (pop, candidates evaluated)."""
+        per key signature. Returns (pop, candidates evaluated, rows placed
+        in a walk block)."""
         ev_ts, ev_kind, ev_valid, ev_cols, ev_nulls = ev
         idx_b = jnp.arange(B, dtype=jnp.int32)
         is_cur = ev_valid & (ev_kind == CURRENT)
         seqmode = self.state_type == "sequence"
-        cands = jnp.int64(0)
+        cands = walk_rows = jnp.int64(0)
         for st in rounds:
             personas = [cs for cs in self.states
                         if cs.is_counting and cs.next_idx == st.idx]
-            pop, n = self._state_round(
+            pop, n, w = self._state_round(
                 pop, st, personas, ev, is_cur, idx_b, B, seqmode, views)
-            cands = cands + n
-        return pop, cands
+            cands, walk_rows = cands + n, walk_rows + w
+        return pop, cands, walk_rows
 
     def _within_ok(self, pop, ts):
         """Rows [P, 1] x event times ``ts`` (broadcastable to [P, X]):
@@ -530,29 +541,24 @@ class ParallelNfaEngine(NfaEngine):
         return {"order": order, "keys": sk, "live": n_live, "end": end,
                 "at": at}
 
-    def _keyed_first(self, pop, st, rk: RoundKey, view, at_rows, ev,
+    def _keyed_round(self, pop, st, rk: RoundKey, view, at_rows, ev,
                      B: int):
-        """Each row's first matching event by key: the row's band of
-        same-hash events (index order within it) from its first event
-        after ``last``, walked one entry per iteration
-        until every row has a match or has left its band — a long band
-        takes more iterations, never a cut. Each entry walked is checked
-        against ``within`` and the state's whole condition; where the
-        sub-batch's event time never decreases, an entry past ``within``
-        ends the walk too (and is not counted). Returns (j, found,
-        entries walked); an empty band walks none."""
-        ev_ts, _, ev_valid, ev_cols, ev_nulls = ev
+        """A keyed round. Over the whole population, each row at the
+        state finds its band of same-hash events (index order within it)
+        and the band's first entry after ``last``: vector ops and gathers
+        of one value per row. Only the rows with an entry left there walk
+        and capture, compacted into blocks of WALK_BLOCK rows, as many
+        blocks as those rows fill (a count read on the device, so none is
+        dropped); the fields the round changes are scattered back. Every
+        other row keeps its state. Returns (pop, entries walked, rows
+        placed in a walk block)."""
+        ev_ts, _, ev_valid, _, _ = ev
         order = view["order"]
-        deadline = None
-        if self.within_ms is not None:
-            # event time in order: the valid events are a prefix and
-            # never go back (else no walk ends early)
-            in_order = jnp.all(ev_valid[1:] <= ev_valid[:-1]) & jnp.all(
-                ~ev_valid[1:] | (ev_ts[1:] >= ev_ts[:-1]))
-            deadline = jnp.where(pop["has_ts0"] & in_order,
-                                 pop["ts0"] + self.within_ms, POS_INF)
         P = at_rows.shape[0]
-        # the row side reads captured slots only: no own slot, no events
+        # the row side reads captured slots only: no own slot, no events.
+        # Rounds whose row sides read the same slots, which no round
+        # between them writes, compute the same hash and band: XLA
+        # computes them once a sub-step
         h, null = _key_hash(rk.row, rk.dtypes,
                             self._env_pairs(pop, (), (), -1, (P, 1)), (P, 1))
         h, active = h[:, 0], at_rows & ~null[:, 0]
@@ -586,6 +592,65 @@ class ParallelNfaEngine(NfaEngine):
 
         found_lo, _ = jax.lax.while_loop(searching, halve, (lo, hi))
         p0 = jnp.where(inside, q + 1, jnp.where(search, found_lo, lo))
+        deadline = jnp.full((P,), POS_INF)
+        if self.within_ms is not None:
+            # event time in order: the valid events are a prefix and
+            # never go back (else no walk ends early)
+            in_order = jnp.all(ev_valid[1:] <= ev_valid[:-1]) & jnp.all(
+                ~ev_valid[1:] | (ev_ts[1:] >= ev_ts[:-1]))
+            deadline = jnp.where(pop["has_ts0"] & in_order,
+                                 pop["ts0"] + self.within_ms, POS_INF)
+
+        # the rows with an entry left in their band, in blocks of W
+        need = active & (p0 < hi)
+        n_need = jnp.sum(need, dtype=jnp.int32)
+        count = running_count(need)
+        W = min(self.WALK_BLOCK, P)
+        lanes = jnp.arange(W, dtype=jnp.int32)
+
+        def pending(c):
+            return c[0] * W < n_need
+
+        def block(c):
+            b, fields, walked = c
+            rows = _nth_true(need, W, count, b * W)
+            real = rows < P
+            at = jnp.minimum(rows, P - 1)
+            # a block reads only its own rows, which no other block writes
+            sub = jax.tree_util.tree_map(
+                lambda x: x[at], self._with_round_fields(pop, st, fields))
+            j, hit, n = self._keyed_first(sub, st, order, p0[at], hi[at],
+                                          deadline[at], real, ev, B)
+            sub = self._advance(sub, st, j, hit,
+                                jnp.zeros((W,), jnp.bool_), ev)
+            # padding lanes write past the end (dropped), each its own
+            dst = jnp.where(real, rows, P + lanes)
+            fields = jax.tree_util.tree_map(
+                lambda full, part: full.at[dst].set(
+                    part, mode="drop", indices_are_sorted=True,
+                    unique_indices=True),
+                fields, self._round_fields(sub, st))
+            return b + 1, fields, walked + n
+
+        _, fields, walked = jax.lax.while_loop(
+            pending, block,
+            (jnp.int32(0), self._round_fields(pop, st), jnp.int64(0)))
+        return (self._with_round_fields(pop, st, fields), walked,
+                n_need.astype(jnp.int64))
+
+    def _keyed_first(self, pop, st, order, p0, hi, deadline, live, ev,
+                     B: int):
+        """The first matching event of each of a block's rows: the row's
+        band of the sorted view from position ``p0`` to ``hi``, walked one
+        entry per iteration until every ``live`` row has a match or has
+        left its band — a long band takes more iterations, never a cut.
+        Each entry walked is checked against ``within`` and the state's
+        whole condition; an entry past the row's ``deadline`` (finite
+        where the sub-batch's event time never decreases) ends the walk
+        too, and is not counted. ``pop`` holds the block's rows only.
+        Returns (j, found, entries walked)."""
+        ev_ts, _, _, ev_cols, ev_nulls = ev
+        P = p0.shape[0]
 
         def walking(c):
             return jnp.any(~c[1])
@@ -599,10 +664,9 @@ class ParallelNfaEngine(NfaEngine):
                                   st.slot, (P, 1))
             ok = inband & (self._cond_ok(st, env, (P, 1)) &
                            self._within_ok(pop, ev_ts[e][:, None]))[:, 0]
-            if deadline is not None:
-                # a later entry of the band is no earlier: past the
-                # deadline, nothing after this entry can match either
-                inband = inband & (ev_ts[e] <= deadline)
+            # a later entry of the band is no earlier: past the deadline,
+            # nothing after this entry can match either
+            inband = inband & (ev_ts[e] <= deadline)
             live = ~done
             walked = walked + jnp.sum(live & (ok | inband), dtype=jnp.int64)
             hit = live & ok
@@ -611,59 +675,33 @@ class ParallelNfaEngine(NfaEngine):
 
         _, _, j, found, walked = jax.lax.while_loop(
             walking, walk,
-            (p0, ~active, jnp.zeros((P,), jnp.int32),
+            (p0, ~live, jnp.zeros((P,), jnp.int32),
              jnp.zeros((P,), jnp.bool_), jnp.int64(0)))
         return j, found, walked
 
-    def _state_round(self, pop, st, personas, ev, is_cur, idx_b, B,
-                     seqmode, views):
+    @staticmethod
+    def _round_fields(pop, st):
+        """The fields a plain round at ``st`` can change: its slot, the
+        first capture's time, ``last``, and the state, or at the final
+        state the emission and ``valid``."""
+        names = ("ts0", "has_ts0", "last") + (
+            ("emit_at", "emit_n", "valid") if st.next_idx == -1
+            else ("state",))
+        return {**{k: pop[k] for k in names}, "slot": pop["slots"][st.slot]}
+
+    @staticmethod
+    def _with_round_fields(pop, st, fields):
+        """``pop`` with the fields ``_round_fields`` names replaced."""
+        rest = {k: v for k, v in fields.items() if k != "slot"}
+        return {**pop, **rest, "slots": tuple(
+            fields["slot"] if k == st.slot else b
+            for k, b in enumerate(pop["slots"]))}
+
+    def _advance(self, pop, st, j, adv, kill, ev):
+        """Rows ``adv`` capture event ``j`` into the state's slot and move
+        on, or emit at the final state; rows ``kill`` die."""
         ev_ts, _, _, ev_cols, ev_nulls = ev
-        P = pop["state"].shape[0]
-        normal = pop["valid"] & (pop["state"] == st.idx)
-        persona = jnp.zeros((P,), jnp.bool_)
-        for cs in personas:
-            persona = persona | (
-                pop["valid"] & (pop["state"] == cs.idx) &
-                (pop["slots"][cs.slot]["n"] >= cs.min_count) &
-                pop["min_prev"])
-        at_rows = normal | persona
-        rk = self.keys.get(st.idx)
-        kill = jnp.zeros((P,), jnp.bool_)
-        if rk is not None:
-            j, has, cands = self._keyed_first(
-                pop, st, rk, views[rk.signature], at_rows, ev, B)
-            adv = at_rows & has
-        else:
-            cands = jnp.int64(P * B)
-            # cheap short-circuit is not possible under jit; grids are DCE'd
-            env = self._env_pairs(pop, [c[None, :] for c in ev_cols],
-                                  [n[None, :] for n in ev_nulls], st.slot,
-                                  (P, B))
-            cond_ok = self._cond_ok(st, env, (P, B))
-            elig = self._eligible(pop, is_cur, idx_b, ev_ts)
-
-            if st.is_counting:
-                return self._counting_round(
-                    pop, st, at_rows, persona, elig & cond_ok, ev_ts,
-                    ev_cols, ev_nulls, B), cands
-
-            if seqmode:
-                # sequence: a NORMAL row's fate is decided by its first
-                # eligible event (advance on match, die on mismatch);
-                # PERSONA rows test every event and are never
-                # sequence-killed (the scan engine's seq_kill applies to
-                # `normal` only)
-                j0, has0 = _first_true(elig)
-                cond_at = jnp.take_along_axis(
-                    cond_ok, j0[:, None].astype(jnp.int64), axis=1)[:, 0]
-                jm, hasm = _first_true(elig & cond_ok)
-                adv = (normal & has0 & cond_at) | (persona & hasm)
-                kill = normal & has0 & ~cond_at
-                j = jnp.where(persona, jm, j0)
-            else:
-                j, has = _first_true(elig & cond_ok)
-                adv = at_rows & has
-
+        P = adv.shape[0]
         pop = self._capture_at(pop, st.slot, jnp.zeros((P,), jnp.int32),
                                ev_cols, ev_nulls, ev_ts, j, adv)
         buf = pop["slots"][st.slot]
@@ -677,16 +715,65 @@ class ParallelNfaEngine(NfaEngine):
                "has_ts0": pop["has_ts0"] | got_first,
                "last": jnp.where(adv, j, pop["last"])}
         if st.next_idx == -1:
-            pop = {**pop,
-                   "emit_at": jnp.where(adv, j, pop["emit_at"]),
-                   "emit_n": jnp.where(adv, jnp.int32(1), pop["emit_n"]),
-                   "valid": pop["valid"] & ~adv & ~kill}
+            return {**pop,
+                    "emit_at": jnp.where(adv, j, pop["emit_at"]),
+                    "emit_n": jnp.where(adv, jnp.int32(1), pop["emit_n"]),
+                    "valid": pop["valid"] & ~adv & ~kill}
+        return {**pop,
+                "state": jnp.where(adv, jnp.int32(st.next_idx),
+                                   pop["state"]),
+                "valid": pop["valid"] & ~kill}
+
+    def _state_round(self, pop, st, personas, ev, is_cur, idx_b, B,
+                     seqmode, views):
+        """One round at ``st``. Returns (pop, candidates evaluated, rows
+        placed in a walk block)."""
+        ev_ts, _, _, ev_cols, ev_nulls = ev
+        P = pop["state"].shape[0]
+        normal = pop["valid"] & (pop["state"] == st.idx)
+        persona = jnp.zeros((P,), jnp.bool_)
+        for cs in personas:
+            persona = persona | (
+                pop["valid"] & (pop["state"] == cs.idx) &
+                (pop["slots"][cs.slot]["n"] >= cs.min_count) &
+                pop["min_prev"])
+        at_rows = normal | persona
+        rk = self.keys.get(st.idx)
+        if rk is not None:
+            return self._keyed_round(pop, st, rk, views[rk.signature],
+                                     at_rows, ev, B)
+        no_walk = jnp.int64(0)
+        cands = jnp.int64(P * B)
+        # cheap short-circuit is not possible under jit; grids are DCE'd
+        env = self._env_pairs(pop, [c[None, :] for c in ev_cols],
+                              [n[None, :] for n in ev_nulls], st.slot,
+                              (P, B))
+        cond_ok = self._cond_ok(st, env, (P, B))
+        elig = self._eligible(pop, is_cur, idx_b, ev_ts)
+
+        if st.is_counting:
+            return self._counting_round(
+                pop, st, at_rows, persona, elig & cond_ok, ev_ts,
+                ev_cols, ev_nulls, B), cands, no_walk
+
+        kill = jnp.zeros((P,), jnp.bool_)
+        if seqmode:
+            # sequence: a NORMAL row's fate is decided by its first
+            # eligible event (advance on match, die on mismatch);
+            # PERSONA rows test every event and are never
+            # sequence-killed (the scan engine's seq_kill applies to
+            # `normal` only)
+            j0, has0 = _first_true(elig)
+            cond_at = jnp.take_along_axis(
+                cond_ok, j0[:, None].astype(jnp.int64), axis=1)[:, 0]
+            jm, hasm = _first_true(elig & cond_ok)
+            adv = (normal & has0 & cond_at) | (persona & hasm)
+            kill = normal & has0 & ~cond_at
+            j = jnp.where(persona, jm, j0)
         else:
-            pop = {**pop,
-                   "state": jnp.where(adv, jnp.int32(st.next_idx),
-                                      pop["state"]),
-                   "valid": pop["valid"] & ~kill}
-        return pop, cands
+            j, has = _first_true(elig & cond_ok)
+            adv = at_rows & has
+        return self._advance(pop, st, j, adv, kill, ev), cands, no_walk
 
     def _counting_round(self, pop, st, at_rows, persona, cand, ev_ts,
                         ev_cols, ev_nulls, B):
@@ -1042,12 +1129,12 @@ class ParallelNfaEngine(NfaEngine):
                 if start is not None and rounds:
                     # the table's rows and the spawned ones advance
                     # alike, independently: one population, one program
-                    both, cands = self._advance_rounds(
+                    both, cands, walk_rows = self._advance_rounds(
                         _concat_pops(pop1, pop2), ev, rounds, views, B)
                     pop1, pop2 = _split_pops(both, M, pop2["born_rel"])
                 else:
-                    pop1, cands = self._advance_rounds(pop1, ev, rounds,
-                                                       views, B)
+                    pop1, cands, walk_rows = self._advance_rounds(
+                        pop1, ev, rounds, views, B)
             pops = [pop1] if start is None else [pop1, pop2]
             completed = live_before + spawned - sum(
                 jnp.sum(p["valid"].astype(jnp.int64)) for p in pops)
@@ -1106,6 +1193,7 @@ class ParallelNfaEngine(NfaEngine):
                     "nfa_completed": table["nfa_completed"] + completed,
                     "nfa_pruned": table["nfa_pruned"] + pruned,
                     "nfa_candidates": table["nfa_candidates"] + cands,
+                    "nfa_walk_rows": table["nfa_walk_rows"] + walk_rows,
                     }, out
 
         def step(table, batch: EventBatch, now):

@@ -207,12 +207,21 @@ KEYED_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(KEYED_CASES))
-def test_keyed_rounds_match_scan_and_reference(case):
+# each case at the default walk block, and again in blocks of 16 rows, so
+# that a round walks its rows in several blocks
+KEYED_RUNS = [pytest.param(c, None, id=c) for c in sorted(KEYED_CASES)] + [
+    pytest.param(c, 16, id=f"{c}-walk_block_16") for c in sorted(KEYED_CASES)]
+
+
+@pytest.mark.parametrize("case,block", KEYED_RUNS)
+def test_keyed_rounds_match_scan_and_reference(case, block, monkeypatch):
     states, keys, step, tie, within = KEYED_CASES[case]
     ts, k, v = keyed_stream(len(case), 1500, keys, step, tie)
     ql = keyed_ql(states, within)
     sends = chunked(ts, k, v, 512)
+    if block is not None:
+        default = run_keyed(ql, sends)[1]
+        monkeypatch.setattr(ParallelNfaEngine, "WALK_BLOCK", block)
     got, stats = run_keyed(ql, sends)
     want = reference_rows(ts, k, v, states, within)
     assert len(want) > 0
@@ -220,25 +229,58 @@ def test_keyed_rounds_match_scan_and_reference(case):
     assert stats["overflow"] == 0
     # a keyed round walks a few band entries per row, not rows x events
     assert stats["nfa_candidates"] <= 4 * (states - 1) * stats["nfa_events"]
+    if block is not None:
+        # some round of some step ran three blocks or more, and the
+        # blocks walked the very entries one block walks
+        rounds = (states - 1) * stats["nfa_steps"]
+        assert stats["nfa_walk_rows"] > 2 * block * rounds
+        assert stats["nfa_candidates"] == default["nfa_candidates"]
+        assert stats["nfa_walk_rows"] == default["nfa_walk_rows"]
     if states == 4:
+        # the scan engine's fixed table (128 rows, 256 matches a step)
+        # holds this case only
         assert run_keyed(ql, sends, force_scan=True)[0] == want
         # another split (into the same 512-event bucket) changes nothing
         assert run_keyed(ql, chunked(ts, k, v, [300, 500, 412, 288]))[0] \
             == want
 
 
-def test_keyed_rounds_survive_colliding_hashes(monkeypatch):
-    """Every key hashing alike puts every event in every row's band: the
-    whole condition, checked on each entry walked, still decides."""
+def _colliding_hashes(monkeypatch):
+    """The 3-state case with every key hashing alike: (rows, stats, the
+    reference's rows)."""
     import siddhi_tpu.ops.nfa_parallel as NP
     monkeypatch.setattr(NP, "hash_columns",
                         lambda cols, nulls: jnp_zeros_like(cols[0]))
     states, keys, step, tie, within = KEYED_CASES["3_states_ties"]
     ts, k, v = keyed_stream(5, 1500, keys, step, tie)
     got, stats = run_keyed(keyed_ql(states, within), chunked(ts, k, v, 512))
-    assert got == reference_rows(ts, k, v, states, within)
+    return got, stats, reference_rows(ts, k, v, states, within)
+
+
+def test_keyed_rounds_survive_colliding_hashes(monkeypatch):
+    """Every key hashing alike puts every event in every row's band: the
+    whole condition, checked on each entry walked, still decides."""
+    got, stats, want = _colliding_hashes(monkeypatch)
+    assert got == want
     # and the walk did cross other keys' events
+    states = 3
     assert stats["nfa_candidates"] > stats["nfa_completed"] * (states - 1)
+    # nearly every row has an entry left in its band, so nearly every
+    # row walks: more walking rows than events
+    assert stats["nfa_walk_rows"] > stats["nfa_events"]
+
+
+def test_keyed_rounds_survive_colliding_hashes_in_small_walk_blocks(
+        monkeypatch):
+    """Every row walking, in blocks of 8 rows: the reference's rows, and
+    the same entries walked as in one block."""
+    got1, stats1, want = _colliding_hashes(monkeypatch)
+    monkeypatch.setattr(ParallelNfaEngine, "WALK_BLOCK", 8)
+    got, stats, _ = _colliding_hashes(monkeypatch)
+    assert got == want == got1
+    assert stats["overflow"] == 0
+    assert stats["nfa_candidates"] == stats1["nfa_candidates"]
+    assert stats["nfa_walk_rows"] == stats1["nfa_walk_rows"]
 
 
 def jnp_zeros_like(x):
@@ -291,6 +333,9 @@ def test_pattern_cap_dial():
     assert len(got) < len(reference_rows(ts, k, v, 2, 1_000_000))
 
 
+KEYED_ROUNDS = 2   # keyed_ql(3, ...): rounds at e2 and e3
+
+
 @pytest.mark.parametrize("pending", [4096, 16])
 def test_counters_add_up(pending):
     """Per step: live_in + spawned - completed - pruned - dropped =
@@ -314,6 +359,11 @@ def test_counters_add_up(pending):
         assert d["nfa_live_in"] + d["nfa_spawned"] - d["nfa_completed"] \
             - d["nfa_pruned"] - d["overflow"] == d["nfa_live_out"]
         assert d["nfa_completed"] == d["emitted"]
+        # a row walks at most once a keyed round, and a completed row
+        # walked in its last round
+        assert d["nfa_walk_rows"] <= \
+            (d["nfa_live_in"] + d["nfa_spawned"]) * KEYED_ROUNDS
+        assert d["nfa_walk_rows"] >= d["nfa_completed"]
         assert d["nfa_live_out"] == int(np.sum(np.asarray(
             q.nfa_state["valid"])))
         assert s["nfa_live_peak"] >= d["nfa_live_out"]
@@ -331,6 +381,7 @@ def test_counters_add_up(pending):
                            if not x.startswith("nfa_")}}
     rt2.queries["q"].restore_state(old)
     assert rt2.queries["q"].stats()["nfa_steps"] == 0
+    assert rt2.queries["q"].stats()["nfa_walk_rows"] == 0
 
 
 def test_batch_longer_than_within_keeps_late_partials():
@@ -362,15 +413,51 @@ def test_batch_longer_than_within_keeps_late_partials():
     assert sum((g - w).values()) + sum((w - g).values()) == 0
 
 
-def test_reference_is_the_benchmarks_own():
+def _linear_road():
+    """The benchmark's Linear Road configuration and its module."""
     import importlib.util
-    import inspect
+    import json
     from pathlib import Path
-    import stopped_reference
-    path = Path(__file__).resolve().parent.parent / "benchmark" / \
-        "configs" / "linear_road_stopped.py"
-    spec = importlib.util.spec_from_file_location("lr_stopped", path)
+    base = Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+    with open(base / "linear_road_stopped.json") as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "lr_stopped", base / "linear_road_stopped.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return config, mod
+
+
+def test_reference_is_the_benchmarks_own():
+    import inspect
+    import stopped_reference
+    _, mod = _linear_road()
     assert inspect.getsource(mod.consecutive_matches) == \
         inspect.getsource(stopped_reference.consecutive_matches)
+
+
+def test_linear_road_tiny_form_walks_few_rows():
+    """The benchmark's Linear Road query at its tiny form, one cycle of
+    256 vehicles (16 of them stop) in 1,024-report chunks: a moving car's
+    band is empty and a spawned row's holds only its own report, so only
+    the stopped cars' rows walk, fewer than one per twenty events."""
+    config, mod = _linear_road()
+    config, _ = mod.tiny(config, {})
+    offsets, cols = mod.generate(np.random.default_rng(2 ** 31 + 9),
+                                 256 * mod.REPORTS)
+    ts = 1_700_000_000_000 + offsets
+    names = [c["name"] for c in config["columns"]]
+    rt = SiddhiManager().create_siddhi_app_runtime(config["app"])
+    got = []
+    rt.add_callback(config["output"], StreamCallback(fn=lambda evs: got.extend(
+        (e.timestamp, *e.data) for e in evs)))
+    rt.start()
+    h = rt.get_input_handler(config["stream"])
+    for a in range(0, len(ts), 1024):
+        h.send_arrays(ts[a:a + 1024], [cols[n][a:a + 1024] for n in names])
+    stats = rt.queries["stopped"].stats()
+    rt.shutdown()
+    want = mod.reference(ts, cols, {})
+    assert len(want) > 0 and got == want
+    assert stats["overflow"] == 0
+    assert stats["nfa_walk_rows"] < 0.05 * stats["nfa_events"]
