@@ -7,7 +7,10 @@ import os
 
 # persistent compile cache: identical-structure queries across test cases
 # (the ref corpus reuses a handful of query shapes over hundreds of cases)
-# compile once per shape instead of once per case. Its own directory:
+# compile once per shape instead of once per case. Across runs that holds
+# only when an earlier run left the cache warm: a fresh checkout starts
+# empty, and tier-1's time limit has to hold without it (cold, a corpus
+# reference class takes about twice its warm time). Its own directory:
 # XLA warns when it loads CPU results built for another machine's
 # feature set (docs/compile_cache.md).
 os.environ.setdefault(

@@ -1,14 +1,19 @@
-"""Replay the reference's pattern/sequence test corpus.
+"""Replay the reference's test corpus.
 
-Fixtures in this directory are machine-extracted from
-/root/reference/modules/siddhi-core/src/test/java/io/siddhi/core/query/
-{pattern,sequence}/** by tools/extract_ref_corpus.py (353 of 409 cases;
-the skipped remainder are loop-driven or API-built tests, listed with
-reasons inside each JSON). Each case replays the reference's exact app
-text, event data, and inter-send sleeps under @app:playback with a
-virtual clock, then asserts the reference's own expected rows/counts —
-the BASELINE.md "bit-equal outputs on the pattern test suite" claim,
-case by case.
+Fixtures in this directory are machine-extracted from the reference's
+TestNG cases (modules/siddhi-core/src/test/java/io/siddhi/core/query/
+{pattern,sequence,window,join}/**) by tools/extract_ref_corpus.py: one
+JSON per reference test class, 443 cases in all. The skipped remainder
+are loop-driven or API-built tests, listed with reasons inside each
+JSON. Each case replays the reference's exact app text, event data, and
+inter-send sleeps under @app:playback with a virtual clock, then asserts
+the reference's own expected rows/counts — the BASELINE.md "bit-equal
+outputs on the pattern test suite" claim, case by case.
+
+Each fixture is collected as its own test file (node id
+``tests/ref_corpus/<stem>.json::<stem>.<name>``), so ``--dist loadfile``
+hands each reference test class to a worker as one unit of work instead
+of the whole corpus as one.
 
 Queries using SiddhiQL features this framework rejects at compile time
 xfail with the CompileError message, keeping the remaining gap inventory
@@ -49,18 +54,45 @@ KNOWN_FAILURES = _id_set("known_failures.txt")
 COMPILE_GATED = _id_set("compile_gated.txt")
 
 
-def _cases():
-    out = []
-    for f in sorted(DIR.glob("*.json")):
-        d = json.loads(f.read_text())
-        stem = f.stem
-        for c in d["cases"]:
-            cid = f"{stem}.{c['name']}"
-            marks = ([pytest.mark.xfail(
-                reason="known output divergence (known_failures.txt)",
-                strict=False)] if cid in KNOWN_FAILURES else [])
-            out.append(pytest.param(c, id=cid, marks=marks))
-    return out
+def fixture_cases(path):
+    """(case id, case) for every case of one fixture JSON; the id is
+    ``<stem>.<name>``, the form the .txt lists use."""
+    for c in json.loads(path.read_text())["cases"]:
+        yield f"{path.stem}.{c['name']}", c
+
+
+def pytest_collect_file(file_path, parent):
+    if file_path.suffix == ".json" and file_path.parent == DIR:
+        return CorpusFile.from_parent(parent, path=file_path)
+    return None
+
+
+class CorpusFile(pytest.File):
+    def collect(self):
+        for cid, case in fixture_cases(self.path):
+            item = CorpusCase.from_parent(self, name=cid, case=case)
+            if cid in KNOWN_FAILURES:
+                item.add_marker(pytest.mark.xfail(
+                    reason="known output divergence (known_failures.txt)",
+                    strict=False))
+            yield item
+
+
+class CorpusCase(pytest.Item):
+    def __init__(self, *, case, **kw):
+        super().__init__(**kw)
+        self.case = case
+
+    def runtest(self):
+        replay(self.case, self.name)
+
+    def repr_failure(self, excinfo):
+        # report from this module's frames down, not pytest's own
+        excinfo.traceback = excinfo.traceback.cut(path=__file__)
+        return super().repr_failure(excinfo)
+
+    def reportinfo(self):
+        return self.path, None, self.name
 
 
 def _rows_match(got, exp):
@@ -83,9 +115,7 @@ def _is_ordered_subset(got_rows, exp_rows):
     return i == len(exp_rows)
 
 
-@pytest.mark.parametrize("case", _cases())
-def test_ref_case(case, request):
-    cid = request.node.callspec.id
+def replay(case, cid):
     mgr = SiddhiManager()
     if case.get("expect_error"):
         # reference @Test(expectedExceptions=SiddhiAppCreationException):

@@ -3,7 +3,8 @@
 - MetricsRegistry instruments + Prometheus exposition
 - statistics() == registry-view equivalence (plain, fused chains,
   partitions, DETAIL latency)
-- BASIC-level overhead bound (<=5% wall on the filter microbench shape)
+- sampled-sync budget on the filter shape: BASIC stats, the cost
+  profiler and SLO tracking add at most one device wait per stride
 - @app:statistics(reporter, interval) parsing + parse-time validation
 - periodic reporters (console/jsonl)
 - service GET /metrics / /health / /ready (readiness tied to
@@ -341,58 +342,107 @@ class TestStatisticsRegistryEquivalence:
 
 
 # ---------------------------------------------------------------------------
-# BASIC-level overhead bound
+# sampled-sync budget on the filter shape: BASIC stats, cost profiler, SLO
 # ---------------------------------------------------------------------------
 
+FILTER_APP = """
+    @app:playback
+    {annotation}
+    define stream S (sym string, price float, volume long);
+    @info(name = 'q')
+    from S[price > 100.0] select sym, price insert into Out;
+"""
+FILTER_CHUNK, FILTER_CHUNKS = 1024, 2 * 64 + 1
 
-def test_basic_stats_overhead_under_5pct_on_filter_shape():
-    """BASIC metrics are host-boundary counters only: on the filter
-    microbench shape they must add <=5% wall time vs stats OFF. Same
-    process, same compiled steps, alternating min-of-N runs so compile
-    and host-contention variance cancel."""
-    rt = _playback_app("""
-        @app:playback
-        define stream S (sym string, price float, volume long);
-        @info(name = 'q')
-        from S[price > 100.0] select sym, price insert into Out;
-    """)
+
+# Each feature: (switch(on), stride, records()) — records() counts what
+# the feature recorded while on, one record per sampled chunk.
+def _basic_stats(rt):
+    """Host-boundary throughput counters mark every chunk."""
+    qs = rt.queries["q"]._qs()
+    return (lambda on: rt.set_statistics_level("BASIC" if on else "OFF"),
+            1, lambda: qs.throughput.count // FILTER_CHUNK)
+
+
+def _cost_profiler(rt):
+    """cost_start() resets the stride, so the first chunk samples."""
+    return (lambda on: rt.cost_start() if on else rt.cost_stop(),
+            rt.cost.every, lambda: rt.cost.samples)
+
+
+def _slo(rt):
+    """The ingest->emit span of a sampled chunk ends at the host decode
+    that delivers the rows anyway."""
+    engine = rt.slo
+    return (lambda on: setattr(rt, "slo", engine if on else None),
+            engine.every,
+            lambda: engine.evaluate()["scopes"].get("query=q",
+                                                    {"count": 0})["count"])
+
+
+@pytest.mark.parametrize("feature,annotation,stride,syncs_per_record", [
+    (_basic_stats, "", 1, 0),
+    (_cost_profiler, "", 64, 1),
+    (_slo, "@app:slo(p99='60 sec', target='0.9')", 64, 0),
+], ids=["basic_stats", "cost_profiler", "slo"])
+def test_sampled_sync_budget_on_filter_shape(feature, annotation, stride,
+                                             syncs_per_record,
+                                             monkeypatch):
+    """A feature on at its default stride adds at most one synchronous
+    device wait per stride of chunks and no other per-chunk device work.
+    Counted, not timed: every ``jax.block_until_ready`` and
+    ``jax.device_get`` of a feature-off run against a feature-on run
+    over the same number of chunks."""
     import jax
-    last = [None]
-    rt.queries["q"].batch_callbacks.append(lambda out: last.__setitem__(
-        0, out))
+    rt = SiddhiManager().create_siddhi_app_runtime(
+        FILTER_APP.format(annotation=annotation))
+    seen = [0]
+    rt.add_callback("Out", StreamCallback(
+        fn=lambda evs: seen.__setitem__(0, seen[0] + len(evs))))
+    rt.start()
+    switch, every, records = feature(rt)
     h = rt.get_input_handler("S")
     rng = np.random.default_rng(7)
-    chunk, chunks = 65_536, 8
     syms = np.array([GLOBAL_STRINGS.encode(s)
                      for s in ("A", "B", "C", "D")], np.int32)
     clock = [TS0]
 
     def run():
-        for _ in range(chunks):
-            ts = clock[0] + np.arange(chunk, dtype=np.int64)
-            clock[0] += chunk
-            h.send_arrays(ts, [syms[rng.integers(0, 4, chunk)],
-                               rng.uniform(0, 200, chunk)
-                               .astype(np.float32),
-                               rng.integers(1, 1000, chunk,
-                                            dtype=np.int64)])
-        jax.block_until_ready(last[0].valid)
+        n = FILTER_CHUNK
+        for _ in range(FILTER_CHUNKS):
+            ts = clock[0] + np.arange(n, dtype=np.int64)
+            clock[0] += n
+            h.send_arrays(ts, [syms[rng.integers(0, 4, n)],
+                               rng.uniform(0, 200, n).astype(np.float32),
+                               rng.integers(1, 1000, n, dtype=np.int64)])
 
-    run()  # warm every step/encoding before timing
-    reps = 5
-    t_off, t_basic = float("inf"), float("inf")
-    for _ in range(reps):
-        rt.set_statistics_level("OFF")
-        t0 = time.perf_counter()
-        run()
-        t_off = min(t_off, time.perf_counter() - t0)
-        rt.set_statistics_level("BASIC")
-        t0 = time.perf_counter()
-        run()
-        t_basic = min(t_basic, time.perf_counter() - t0)
+    syncs = [0]
+
+    def counting(real):
+        def wrapped(*a, **kw):
+            syncs[0] += 1
+            return real(*a, **kw)
+        return wrapped
+
+    switch(False)
+    run()   # compile every step/encoding before counting
+    monkeypatch.setattr(jax, "block_until_ready",
+                        counting(jax.block_until_ready))
+    monkeypatch.setattr(jax, "device_get", counting(jax.device_get))
+    run()
+    syncs_off, syncs[0] = syncs[0], 0
+    assert records() == 0
+    switch(True)
+    run()
+    syncs_on = syncs[0]
+    monkeypatch.undo()
     rt.shutdown()
-    # 10 ms absolute floor absorbs scheduler jitter on sub-100ms runs
-    assert t_basic <= t_off * 1.05 + 0.010, (t_off, t_basic)
+    assert seen[0] > 0
+    assert every == stride              # the documented default stride
+    sampled = -(-FILTER_CHUNKS // stride)
+    assert records() == sampled, (records(), sampled)
+    assert syncs_on == syncs_off + syncs_per_record * sampled, \
+        (syncs_off, syncs_on, sampled)
 
 
 # ---------------------------------------------------------------------------

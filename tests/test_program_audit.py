@@ -11,8 +11,8 @@ Four layers, mirroring the PR 16 semantic-lint gate discipline:
   SARIF output validates against the vendored 2.1.0 schema subset and
   names the offending program spec;
 - gates: the curated repo suite (tools/audit_suite/) and a bounded,
-  deterministic slice of the reference corpus audit CLEAN within a hard
-  time budget against the shipped EMPTY baseline
+  deterministic slice of the reference corpus audit CLEAN, compiling
+  nothing, against the shipped EMPTY baseline
   (tools/audit_baseline.json); the full struct-deduplicated corpus
   sweep runs under ``-m slow``;
 - surfacing: the audit block rides statistics()['compile']['audit'] and
@@ -23,7 +23,6 @@ Four layers, mirroring the PR 16 semantic-lint gate discipline:
 import io
 import json
 import pathlib
-import time
 
 import jax
 import pytest
@@ -275,15 +274,12 @@ def test_repo_suite_audits_clean_within_budget():
     # the measured pass isolates the audit, which must compile nothing
     _cli(str(SUITE))
     before = compile_mod.cache_counts()
-    t0 = time.monotonic()
     code, text = _cli(str(SUITE))
-    elapsed = time.monotonic() - t0
     assert code == 0, text
     assert "0 new finding(s)" in text
     after = compile_mod.cache_counts()
     assert after["misses"] == before["misses"], \
         "suite audit compiled new programs"
-    assert elapsed < 60.0, f"suite audit took {elapsed:.1f}s"
 
 
 def _corpus_cases(round_robin=False):
@@ -314,10 +310,15 @@ def _corpus_cases(round_robin=False):
     return out
 
 
+# the tier-1 slice: ~15 s with a cold compile cache on an 8-core host
+CORPUS_SLICE = 8
+
+
 def test_corpus_slice_audits_clean_within_budget(monkeypatch):
     """PR 16 gate pattern: a bounded, deterministic slice of the
-    reference corpus audits CLEAN in tier-1 time (the full sweep runs
-    under -m slow and via `tools/audit.py --corpus`). Zero new
+    reference corpus — its first CORPUS_SLICE struct-distinct apps,
+    round robin over the corpus files — audits CLEAN (the full sweep
+    runs under -m slow and via `tools/audit.py --corpus`). Zero new
     compiles and zero device reads across the whole slice."""
     from siddhi_tpu.analysis.programs import audit_runtime
     from siddhi_tpu.lang.tokens import SiddhiParserException
@@ -332,11 +333,8 @@ def test_corpus_slice_audits_clean_within_budget(monkeypatch):
 
     monkeypatch.setattr(jax, "device_get", counting_get)
     mgr = SiddhiManager()
-    t0 = time.monotonic()
     audited, dirty = 0, []
-    for rel, text in _corpus_cases(round_robin=True):
-        if time.monotonic() - t0 > 10.0:
-            break  # hard slice bound — the full sweep is -m slow
+    for rel, text in _corpus_cases(round_robin=True)[:CORPUS_SLICE]:
         try:
             rt = mgr.create_siddhi_app_runtime(text)
         except (CompileError, SiddhiParserException):
@@ -347,10 +345,8 @@ def test_corpus_slice_audits_clean_within_budget(monkeypatch):
         dirty += [f"{rel}: {f.render()}" for f in rep.findings]
         audited += 1
     monkeypatch.undo()
-    elapsed = time.monotonic() - t0
     assert not dirty, "\n".join(dirty[:10])
-    assert audited >= 3, f"slice covered only {audited} apps"
-    assert elapsed < 15.0, f"corpus slice took {elapsed:.1f}s"
+    assert audited == CORPUS_SLICE, f"slice audited only {audited} apps"
     assert gets[0] == 0, "audit performed device reads"
     # runtime CONSTRUCTION may compile eager fills (misses on a cold
     # cache); the audit itself must compile nothing

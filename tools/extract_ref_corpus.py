@@ -6,7 +6,7 @@ EveryPatternTestCase.java:48-99): build a SiddhiQL string, attach a
 QueryCallback counting events and asserting row data, send Object[] rows
 with Thread.sleep gaps, then assert final counts. This script parses that
 idiom and emits data-driven fixtures replayed by
-tests/ref_corpus/test_corpus.py under @app:playback with a virtual clock
+tests/ref_corpus/conftest.py under @app:playback with a virtual clock
 (sleeps become clock advances), proving output parity case by case.
 
 Run:  python tools/extract_ref_corpus.py   (writes tests/ref_corpus/*.json)

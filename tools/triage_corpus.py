@@ -17,29 +17,23 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
                        / "tests" / "ref_corpus"))
 
-import test_corpus as tc  # noqa: E402
+import conftest as corpus  # noqa: E402
 from siddhi_tpu.lang.tokens import SiddhiParserException  # noqa: E402
 from siddhi_tpu.ops.expr import CompileError  # noqa: E402
 from _pytest.outcomes import XFailed  # noqa: E402
 
 
-class _Req:
-    """Duck-typed pytest `request` (test_ref_case reads callspec.id)."""
-    def __init__(self, cid):
-        self.node = type("N", (), {"callspec": type("C", (), {"id": cid})()})()
-
-
 def main():
     only = sys.argv[1] if len(sys.argv) > 1 else ""
     out = collections.defaultdict(list)
-    for p in tc._cases():
-        case = p.values[0]
-        cid = p.id
+    cases = [c for f in sorted(corpus.DIR.glob("*.json"))
+             for c in corpus.fixture_cases(f)]
+    for cid, case in cases:
         if only and only not in cid:
             continue
         print(f"... {cid}", file=sys.stderr, flush=True)
         try:
-            tc.test_ref_case(case, _Req(cid))
+            corpus.replay(case, cid)
             out["pass"].append(cid)
         except XFailed as e:
             out["compile"].append((cid, str(e)[:90]))
