@@ -1197,8 +1197,8 @@ class PatternQueryRuntime(QueryRuntime):
         table in one transfer (ops/nfa_parallel.py ``COUNTERS``): steps,
         events, live rows at step start and end (summed over steps), the
         live peak, rows spawned, completed and pruned, the row x event
-        candidates the rounds evaluated, and the rows keyed rounds placed
-        in a walk block."""
+        candidates the rounds evaluated, the rows keyed rounds placed in
+        a walk block, and the iterations of the keyed band lookups."""
         out = super().stats()
         with self._lock:
             nfa = self.nfa_state

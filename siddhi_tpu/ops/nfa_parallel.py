@@ -26,16 +26,19 @@ state's condition at plan time (``round_key``):
   - keyed: the condition has an equality conjunct between the incoming
     event and a captured slot (``vid == e1.vid``). The sub-batch's events
     are sorted once by a hash of the event sides, with the event index as
-    tiebreak (``sorted_key_view``); each row finds its band of same-hash
-    events with one searchsorted and its first entry after ``last``
-    (vector ops and one-value gathers over the whole population). Only
+    tiebreak (``sorted_key_view``), with a bucket directory of the
+    hashes' top bits; each row finds its band of same-hash events by a
+    binary search inside its bucket, once a sub-step for the rounds that
+    key alike, and its first entry after ``last`` (vector ops and
+    one-value gathers over the whole population). Only
     the rows with an entry left there walk the band, compacted into
     blocks of WALK_BLOCK rows: a block walks until an entry passes
     ``within`` and the WHOLE condition (so a hash collision never
     matches), captures it and scatters back the fields the round
-    changes. O((M + B) log B) per round for M rows and B events, plus
-    the walk and capture of the N rows with an entry left, in
-    ceil(N / WALK_BLOCK) blocks, times the longest walk;
+    changes. O(B log B + M log W) a sub-step for M rows, B events and
+    W events in the widest bucket, plus per round the walk and capture
+    of the N rows with an entry left, in ceil(N / WALK_BLOCK) blocks,
+    times the longest walk;
   - grid: every other state evaluates its condition on a [rows, events]
     grid, O(M * B) per round, over sub-batches of at most PB events.
 
@@ -87,12 +90,13 @@ NO_EMIT = np.int64(2 ** 62)  # emission key of a row that emits nothing
 # start and end; the most live rows after any sub-step; rows spawned
 # alive; rows that completed (or a sequence mismatch killed) in the
 # rounds; rows `within` pruned; row x event pairs whose condition a
-# round evaluated; rows a keyed round placed in a walk block. Per step,
-# live_in + spawned - completed - pruned - rows the fold dropped (counted
-# in `overflow`) = live_out.
+# round evaluated; rows a keyed round placed in a walk block; iterations
+# of the band lookups' binary search. Per step, live_in + spawned -
+# completed - pruned - rows the fold dropped (counted in `overflow`) =
+# live_out.
 COUNTERS = ("nfa_steps", "nfa_events", "nfa_live_in", "nfa_live_out",
             "nfa_live_peak", "nfa_spawned", "nfa_completed", "nfa_pruned",
-            "nfa_candidates", "nfa_walk_rows")
+            "nfa_candidates", "nfa_walk_rows", "nfa_band_iters")
 
 
 def _cond_refs_own_indexed(st: NfaStateSpec, slots: list[SlotSpec]) -> bool:
@@ -185,12 +189,16 @@ class RoundKey:
     incoming event with captured slots (``vid == e1.vid``): both sides of
     each are cast to the type the conjunct compares in and hashed
     together. States whose event sides read the same (``signature``)
-    share one sorted view of a sub-batch."""
+    share one sorted view of a sub-batch; those whose row sides read the
+    same too (``band_key``) share each row's band, until a round writes
+    one of the slots the row side reads (``row_slots``)."""
 
     event: tuple       # CompiledExpr over the incoming event
     row: tuple         # CompiledExpr over the row's captured slots
     dtypes: tuple      # numpy dtype each conjunct compares in
     signature: tuple
+    band_key: tuple
+    row_slots: frozenset
 
 
 class _SlotRecorder(Scope):
@@ -241,7 +249,7 @@ def round_key(st: NfaStateSpec, slots: list[SlotSpec]) -> Optional[RoundKey]:
     (such a state runs the grid)."""
     if st.cond_ast is None or st.is_counting:
         return None
-    ev, row, dts, sig = [], [], [], []
+    ev, row, dts, sig, row_sig = [], [], [], [], []
     for conj in _flatten_and(st.cond_ast):
         if not (isinstance(conj, A.Compare) and conj.op == "=="):
             continue
@@ -252,10 +260,11 @@ def round_key(st: NfaStateSpec, slots: list[SlotSpec]) -> Optional[RoundKey]:
                 ce = compile_expression(e, rec)
             except CompileError:
                 break
-            sides[_side(rec.keys, st.slot)] = (ce, e)
+            sides[_side(rec.keys, st.slot)] = (ce, e, tuple(rec.keys))
         if set(sides) != {"event", "row"}:
             continue
-        (ce_e, ast_e), (ce_r, _) = sides["event"], sides["row"]
+        ce_e, ast_e, _ = sides["event"]
+        ce_r, ast_r, keys_r = sides["row"]
         dt = _key_dtype(ce_e.type, ce_r.type)
         if dt is None:
             continue
@@ -263,10 +272,13 @@ def round_key(st: NfaStateSpec, slots: list[SlotSpec]) -> Optional[RoundKey]:
         row.append(ce_r)
         dts.append(dt)
         sig.append((repr(_unref(ast_e)), dt.str))
+        row_sig.append((repr(ast_r), keys_r))
     if not ev:
         return None
-    return RoundKey(tuple(ev), tuple(row), tuple(dts),
-                    (st.stream_id,) + tuple(sig))
+    signature = (st.stream_id,) + tuple(sig)
+    return RoundKey(tuple(ev), tuple(row), tuple(dts), signature,
+                    signature + tuple(row_sig),
+                    frozenset(j for r in row_sig for _, j, _, _ in r[1]))
 
 
 def _high32(h):
@@ -284,6 +296,81 @@ def _key_hash(exprs, dtypes, env, shape):
         vals.append(v)
         nulls.append(jnp.broadcast_to(c.nulls, shape))
     return hash_columns(vals, nulls), functools.reduce(jnp.logical_or, nulls)
+
+
+def _dir_bits(B: int) -> int:
+    """Bits of the bucket directory over a sub-batch of B events: about
+    four buckets an event."""
+    return max(B - 1, 1).bit_length() + 2
+
+
+def _bucket(key, bits: int):
+    """The top ``bits`` bits of int32 keys as a bucket in [0, 2**bits),
+    in the keys' order."""
+    return (key >> (32 - bits)) + np.int32(2 ** (bits - 1))
+
+
+def _sorted_view(keys, live):
+    """The sub-batch's events sorted by (int32 key, event index), live
+    events first: order, sorted keys, live count; per position, where its
+    run of equal keys ends and where each event sits; and a bucket
+    directory of the sorted keys' top ``_dir_bits`` bits, each bucket's
+    span [start, stop) of positions (an empty bucket's span is empty).
+    Equal buckets are contiguous, so a span is a run's first and last
+    position: two scatters of distinct indices, no search over the
+    buckets and no scan."""
+    B = keys.shape[0]
+    order, sk, n_live = sorted_key_view(keys, live)
+    order, n_live = order.astype(jnp.int32), n_live.astype(jnp.int32)
+    pos = jnp.arange(B, dtype=jnp.int32)
+    end = jnp.minimum(jnp.searchsorted(sk, sk, side="right")
+                      .astype(jnp.int32), n_live)
+    at = jnp.zeros((B,), jnp.int32).at[order].set(pos, unique_indices=True)
+    bits = _dir_bits(B)
+    t = _bucket(sk, bits)
+    edge = jnp.full((1,), -1, jnp.int32)
+    in_run = pos < n_live
+    opens = in_run & (t != jnp.concatenate([edge, t[:-1]]))
+    closes = in_run & ((t != jnp.concatenate([t[1:], edge])) |
+                       (pos == n_live - 1))
+
+    def span(mask, vals):
+        # the other positions write past the end (dropped), each its own
+        dst = jnp.where(mask, t, 2 ** bits + pos)
+        return jnp.zeros((2 ** bits,), jnp.int32).at[dst].set(
+            vals, mode="drop", unique_indices=True)
+
+    return {"order": order, "keys": sk, "live": n_live, "end": end,
+            "at": at, "start": span(opens, pos), "stop": span(closes, pos + 1)}
+
+
+def _band_lookup(view, key):
+    """Each int32 ``key``'s band in ``view`` (``_sorted_view``): the
+    positions [lo, hi) of the live events with that key, empty where it
+    has none. A binary search inside the key's bucket, run while some
+    key's interval is non-empty: as many iterations as the widest bucket
+    takes, at most bit_length(B) for B events. Returns (lo, hi,
+    iterations)."""
+    keys = view["keys"]
+    B = keys.shape[0]
+    t = _bucket(key, _dir_bits(B))
+
+    def searching(c):
+        return jnp.any(c[0] < c[1])
+
+    def halve(c):
+        lo, top, n = c
+        mid = (lo + top) // 2
+        more = lo < top
+        below = keys[jnp.minimum(mid, B - 1)] < key
+        return (jnp.where(more & below, mid + 1, lo),
+                jnp.where(more & ~below, mid, top), n + 1)
+
+    lo, _, n = jax.lax.while_loop(
+        searching, halve, (view["start"][t], view["stop"][t], jnp.int64(0)))
+    at_lo = jnp.minimum(lo, B - 1)
+    hit = (lo < view["live"]) & (keys[at_lo] == key)
+    return lo, jnp.where(hit, view["end"][at_lo], lo), n
 
 
 def _lexsort32(key, seq):
@@ -483,19 +570,30 @@ class ParallelNfaEngine(NfaEngine):
         eligibility starting after its captured event. ev = (ts, kind,
         valid, cols, nulls); ``views`` holds the sub-batch's sorted view
         per key signature. Returns (pop, candidates evaluated, rows placed
-        in a walk block)."""
+        in a walk block, band search iterations)."""
         ev_ts, ev_kind, ev_valid, ev_cols, ev_nulls = ev
         idx_b = jnp.arange(B, dtype=jnp.int32)
         is_cur = ev_valid & (ev_kind == CURRENT)
         seqmode = self.state_type == "sequence"
-        cands = walk_rows = jnp.int64(0)
+        cands = walk_rows = band_iters = jnp.int64(0)
+        # band key -> (slots its row side reads, each row's band): looked
+        # up once for the rounds that key alike, until a round writes one
+        # of those slots
+        bands = {}
         for st in rounds:
             personas = [cs for cs in self.states
                         if cs.is_counting and cs.next_idx == st.idx]
+            rk = self.keys.get(st.idx)
+            if rk is not None and rk.band_key not in bands:
+                band = self._row_band(pop, rk, views[rk.signature])
+                bands[rk.band_key] = (rk.row_slots, band)
+                band_iters = band_iters + band[3]
             pop, n, w = self._state_round(
-                pop, st, personas, ev, is_cur, idx_b, B, seqmode, views)
+                pop, st, personas, ev, is_cur, idx_b, B, seqmode, views,
+                bands)
             cands, walk_rows = cands + n, walk_rows + w
-        return pop, cands, walk_rows
+            bands = {k: b for k, b in bands.items() if st.slot not in b[0]}
+        return pop, cands, walk_rows, band_iters
 
     def _within_ok(self, pop, ts):
         """Rows [P, 1] x event times ``ts`` (broadcastable to [P, X]):
@@ -521,54 +619,44 @@ class ParallelNfaEngine(NfaEngine):
         return jnp.broadcast_to(c.values & ~c.nulls, shape)
 
     def _key_view(self, st, rk: RoundKey, ev, B: int):
-        """The sub-batch's events sorted by (key hash, event index):
-        (order, sorted hashes, live count). The hash is cut to its high
-        32 bits, as the sort keys are all 32-bit (a 64-bit sort key at
-        65,536 events takes the TPU compiler minutes); events that are
+        """The sub-batch's events sorted by (key hash, event index), with
+        their bucket directory (``_sorted_view``). The hash is cut to its
+        high 32 bits, as the sort keys are all 32-bit (a 64-bit sort key
+        at 65,536 events takes the TPU compiler minutes); events that are
         not CURRENT or whose key is null sort last."""
         _, ev_kind, ev_valid, ev_cols, ev_nulls = ev
         h, null = _key_hash(rk.event, rk.dtypes,
                             self._virtual_env_b(st, ev_cols, ev_nulls), (B,))
-        live = ev_valid & (ev_kind == CURRENT) & ~null
-        order, sk, n_live = sorted_key_view(_high32(h), live)
-        order = order.astype(jnp.int32)
-        # where each position's run of equal hashes ends, and where each
-        # event sits in the sorted order
-        end = jnp.minimum(jnp.searchsorted(sk, sk, side="right")
-                          .astype(jnp.int32), n_live)
-        at = jnp.zeros((B,), jnp.int32).at[order].set(
-            jnp.arange(B, dtype=jnp.int32), unique_indices=True)
-        return {"order": order, "keys": sk, "live": n_live, "end": end,
-                "at": at}
+        return _sorted_view(_high32(h),
+                            ev_valid & (ev_kind == CURRENT) & ~null)
 
-    def _keyed_round(self, pop, st, rk: RoundKey, view, at_rows, ev,
-                     B: int):
+    def _row_band(self, pop, rk: RoundKey, view):
+        """Each row's band of same-hash events in ``view``, whatever its
+        state: the row side reads captured slots only (no own slot, no
+        events). Returns (lo, hi, key null, search iterations)."""
+        P = pop["state"].shape[0]
+        h, null = _key_hash(rk.row, rk.dtypes,
+                            self._env_pairs(pop, (), (), -1, (P, 1)), (P, 1))
+        lo, hi, n = _band_lookup(view, _high32(h[:, 0]))
+        return lo, hi, null[:, 0], n
+
+    def _keyed_round(self, pop, st, view, band, at_rows, ev, B: int):
         """A keyed round. Over the whole population, each row at the
-        state finds its band of same-hash events (index order within it)
-        and the band's first entry after ``last``: vector ops and gathers
-        of one value per row. Only the rows with an entry left there walk
-        and capture, compacted into blocks of WALK_BLOCK rows, as many
-        blocks as those rows fill (a count read on the device, so none is
-        dropped); the fields the round changes are scattered back. Every
-        other row keeps its state. Returns (pop, entries walked, rows
-        placed in a walk block)."""
+        state takes its band of same-hash events (index order within it;
+        ``band``, from ``_row_band``) and finds the band's first entry
+        after ``last``: vector ops and gathers of one value per row. Only
+        the rows with an entry left there walk and capture, compacted
+        into blocks of WALK_BLOCK rows, as many blocks as those rows fill
+        (a count read on the device, so none is dropped); the fields the
+        round changes are scattered back. Every other row keeps its
+        state. Returns (pop, entries walked, rows placed in a walk
+        block)."""
         ev_ts, _, ev_valid, _, _ = ev
         order = view["order"]
         P = at_rows.shape[0]
-        # the row side reads captured slots only: no own slot, no events.
-        # Rounds whose row sides read the same slots, which no round
-        # between them writes, compute the same hash and band: XLA
-        # computes them once a sub-step
-        h, null = _key_hash(rk.row, rk.dtypes,
-                            self._env_pairs(pop, (), (), -1, (P, 1)), (P, 1))
-        h, active = h[:, 0], at_rows & ~null[:, 0]
-        # the row's band: one search for its start, its end from the view
-        key = _high32(h)
-        lo = jnp.minimum(jnp.searchsorted(view["keys"], key, side="left")
-                         .astype(jnp.int32), view["live"])
-        at_lo = jnp.minimum(lo, B - 1)
-        hi = jnp.where(active & (lo < view["live"]) &
-                       (view["keys"][at_lo] == key), view["end"][at_lo], lo)
+        lo, hi, null, _ = band
+        active = at_rows & ~null
+        hi = jnp.where(active, hi, lo)
         # the band's first entry after `last`: next to `last` itself where
         # that event lies in the band (it does where both sides of the key
         # read the same values, vid == e1.vid); else a binary search,
@@ -725,7 +813,7 @@ class ParallelNfaEngine(NfaEngine):
                 "valid": pop["valid"] & ~kill}
 
     def _state_round(self, pop, st, personas, ev, is_cur, idx_b, B,
-                     seqmode, views):
+                     seqmode, views, bands):
         """One round at ``st``. Returns (pop, candidates evaluated, rows
         placed in a walk block)."""
         ev_ts, _, _, ev_cols, ev_nulls = ev
@@ -740,8 +828,8 @@ class ParallelNfaEngine(NfaEngine):
         at_rows = normal | persona
         rk = self.keys.get(st.idx)
         if rk is not None:
-            return self._keyed_round(pop, st, rk, views[rk.signature],
-                                     at_rows, ev, B)
+            return self._keyed_round(pop, st, views[rk.signature],
+                                     bands[rk.band_key][1], at_rows, ev, B)
         no_walk = jnp.int64(0)
         cands = jnp.int64(P * B)
         # cheap short-circuit is not possible under jit; grids are DCE'd
@@ -1129,12 +1217,13 @@ class ParallelNfaEngine(NfaEngine):
                 if start is not None and rounds:
                     # the table's rows and the spawned ones advance
                     # alike, independently: one population, one program
-                    both, cands, walk_rows = self._advance_rounds(
-                        _concat_pops(pop1, pop2), ev, rounds, views, B)
+                    both, cands, walk_rows, band_iters = \
+                        self._advance_rounds(_concat_pops(pop1, pop2), ev,
+                                             rounds, views, B)
                     pop1, pop2 = _split_pops(both, M, pop2["born_rel"])
                 else:
-                    pop1, cands, walk_rows = self._advance_rounds(
-                        pop1, ev, rounds, views, B)
+                    pop1, cands, walk_rows, band_iters = \
+                        self._advance_rounds(pop1, ev, rounds, views, B)
             pops = [pop1] if start is None else [pop1, pop2]
             completed = live_before + spawned - sum(
                 jnp.sum(p["valid"].astype(jnp.int64)) for p in pops)
@@ -1194,6 +1283,7 @@ class ParallelNfaEngine(NfaEngine):
                     "nfa_pruned": table["nfa_pruned"] + pruned,
                     "nfa_candidates": table["nfa_candidates"] + cands,
                     "nfa_walk_rows": table["nfa_walk_rows"] + walk_rows,
+                    "nfa_band_iters": table["nfa_band_iters"] + band_iters,
                     }, out
 
         def step(table, batch: EventBatch, now):

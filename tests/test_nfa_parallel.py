@@ -77,6 +77,11 @@ QLS = [
     "select e1[0].v as a, e2.v as b insert into O;",
     "from e1=A<2:4> -> e2=B[v > 9] "
     "select e1[0].v as a, e1[1].v as a2, e2.v as b insert into O;",
+    # e2 and e4 key alike on a slot e3's round writes between them: e4
+    # looks its band up again, with the e3.w just captured
+    "from every e1=A[v > 3] -> e2=A[w == coalesce(e3.w, e1.w)] "
+    "-> e3=A[v > e1.v] -> e4=A[w == coalesce(e3.w, e1.w)] "
+    "select e1.v as a, e3.w as w3, e4.w as w insert into O;",
 ]
 
 
@@ -229,6 +234,11 @@ def test_keyed_rounds_match_scan_and_reference(case, block, monkeypatch):
     assert stats["overflow"] == 0
     # a keyed round walks a few band entries per row, not rows x events
     assert stats["nfa_candidates"] <= 4 * (states - 1) * stats["nfa_events"]
+    # every round keys on e1's slot, which none writes: one band lookup a
+    # step, a search inside the buckets of at most bit_length(B)
+    # iterations for the B = 1,024 events of a 512-event chunk's bucket
+    assert stats["nfa_steps"] <= stats["nfa_band_iters"] <= \
+        stats["nfa_steps"] * (1024).bit_length()
     if block is not None:
         # some round of some step ran three blocks or more, and the
         # blocks walked the very entries one block walks
@@ -236,6 +246,7 @@ def test_keyed_rounds_match_scan_and_reference(case, block, monkeypatch):
         assert stats["nfa_walk_rows"] > 2 * block * rounds
         assert stats["nfa_candidates"] == default["nfa_candidates"]
         assert stats["nfa_walk_rows"] == default["nfa_walk_rows"]
+        assert stats["nfa_band_iters"] == default["nfa_band_iters"]
     if states == 4:
         # the scan engine's fixed table (128 rows, 256 matches a step)
         # holds this case only
@@ -253,7 +264,13 @@ def _colliding_hashes(monkeypatch):
                         lambda cols, nulls: jnp_zeros_like(cols[0]))
     states, keys, step, tie, within = KEYED_CASES["3_states_ties"]
     ts, k, v = keyed_stream(5, 1500, keys, step, tie)
-    got, stats = run_keyed(keyed_ql(states, within), chunked(ts, k, v, 512))
+    sends = chunked(ts, k, v, 512)
+    got, stats = run_keyed(keyed_ql(states, within), sends)
+    # one bucket holds a chunk's every event: each step's one band lookup
+    # halves the whole chunk, bit_length(events) iterations, at most 17
+    assert stats["nfa_band_iters"] == sum(
+        len(ts_c).bit_length() for ts_c, _ in sends)
+    assert stats["nfa_band_iters"] <= 17 * stats["nfa_steps"]
     return got, stats, reference_rows(ts, k, v, states, within)
 
 
@@ -281,11 +298,92 @@ def test_keyed_rounds_survive_colliding_hashes_in_small_walk_blocks(
     assert stats["overflow"] == 0
     assert stats["nfa_candidates"] == stats1["nfa_candidates"]
     assert stats["nfa_walk_rows"] == stats1["nfa_walk_rows"]
+    assert stats["nfa_band_iters"] == stats1["nfa_band_iters"]
 
 
 def jnp_zeros_like(x):
     import jax.numpy as jnp
     return jnp.zeros(x.shape, jnp.int64)
+
+
+# -- the band lookup: each key's band of the sorted view, found through a
+# bucket directory of the keys' top bits, is searchsorted's band ---------
+
+I32 = np.iinfo(np.int32)
+
+
+def _band_case(case, rng):
+    """(a sub-batch's keys, which are live, the keys looked up)."""
+    from siddhi_tpu.ops.nfa_parallel import _dir_bits
+    if case == "random":
+        keys = rng.integers(I32.min, I32.max, 4096, endpoint=True)
+        live = rng.random(4096) < 0.9
+        probe = np.concatenate([keys, rng.integers(I32.min, I32.max, 2048)])
+    elif case == "shared_top_bits":
+        # 16 buckets, each holding ~250 events of ~300 distinct low bits
+        shift = 32 - _dir_bits(4096)
+        top = rng.integers(-2 ** (31 - shift), 2 ** (31 - shift), 16)
+        low = rng.integers(0, 2 ** shift, 300)
+        keys = (rng.choice(top, 4096) << shift) + rng.choice(low, 4096)
+        live = rng.random(4096) < 0.9
+        probe = np.concatenate([keys, (rng.choice(top, 2048) << shift) +
+                                rng.integers(0, 2 ** shift, 2048)])
+    elif case == "one_bucket":
+        # all 65,536 events in one bucket: the search halves them all,
+        # for the bucket's least key to the very first position
+        shift = 32 - _dir_bits(65536)
+        keys = (5 << shift) + rng.integers(0, 2 ** shift, 65536)
+        live = np.ones(65536, bool)
+        probe = np.concatenate([keys[:4096], (5 << shift) +
+                                rng.integers(0, 2 ** shift, 1024),
+                                [5 << shift]])
+    elif case == "int32_extremes":
+        # live keys at both ends of int32, among them the padding
+        # sentinel (int32 max) that the dead events sort under
+        keys = rng.choice([I32.min, I32.min + 1, -1, 0, I32.max - 1,
+                           I32.max], 1024)
+        live = rng.random(1024) < 0.75
+        probe = np.array([I32.min, I32.min + 1, I32.min + 2, -1, 0, 1,
+                          I32.max - 2, I32.max - 1, I32.max])
+    elif case == "no_live":
+        keys = rng.integers(I32.min, I32.max, 1024)
+        live = np.zeros(1024, bool)
+        probe = keys
+    else:   # "absent": even keys, odd ones looked up
+        keys = rng.integers(I32.min // 2, I32.max // 2, 4096) * 2
+        live = np.ones(4096, bool)
+        probe = keys[:2048] + 1
+    return keys.astype(np.int32), live, probe.astype(np.int32)
+
+
+@pytest.mark.parametrize("case", ["random", "shared_top_bits", "one_bucket",
+                                  "int32_extremes", "no_live", "absent"])
+def test_band_lookup_matches_searchsorted(case):
+    import jax.numpy as jnp
+    from siddhi_tpu.ops.nfa_parallel import _band_lookup, _sorted_view
+    keys, live, probe = _band_case(case, np.random.default_rng(len(case)))
+    view = _sorted_view(jnp.asarray(keys), jnp.asarray(live))
+    lo, hi, iters = _band_lookup(view, jnp.asarray(probe))
+    lo, hi, iters = np.asarray(lo), np.asarray(hi), int(iters)
+    n_live = int(view["live"])
+    want_lo, want_hi = (np.minimum(np.asarray(jnp.searchsorted(
+        view["keys"], probe, side=side)), n_live)
+        for side in ("left", "right"))
+    present = np.isin(probe, keys[live])
+    assert np.array_equal(want_lo < want_hi, present)
+    # a present key's band is searchsorted's; an absent key's is empty
+    assert np.array_equal(lo[present], want_lo[present])
+    assert np.array_equal(hi[present], want_hi[present])
+    assert np.array_equal(lo[~present], hi[~present])
+    # the search runs while some key's interval inside its bucket is
+    # non-empty: bit_length of the widest bucket's events (~3,700 random
+    # keys in 16,384 buckets put at most 7 in one)
+    assert iters <= len(keys).bit_length()
+    widest = {"one_bucket": 65536, "no_live": 0, "random": 7}.get(case)
+    if case in ("one_bucket", "no_live"):
+        assert iters == widest.bit_length()
+    elif widest is not None:
+        assert iters <= widest.bit_length()
 
 
 def test_round_kind_is_chosen_from_the_query():
